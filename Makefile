@@ -23,7 +23,7 @@ check-goldens:
 # The committed autotune cache must COVER every fused layer shape of the
 # benchmarked configs (default 24x32 + the large-input 96x128) — lookups
 # for uncovered shapes silently fall back to the untuned default, which
-# is bit-identical but forfeits the tuned crossover. Fails on a stale
+# is bit-identical but forfeits the tuned tiling. Fails on a stale
 # (version-bumped) cache too, since that loads as empty. Regenerate with:
 #   PYTHONPATH=src python -m repro.kernels.autotune --input-hw 96x128
 check-autotune:

@@ -25,7 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

@@ -37,14 +37,10 @@ def _enable_cpu_collectives() -> None:
     """Select the gloo CPU collectives backend — REQUIRED before the first
     backend touch, or multi-process ``JAX_PLATFORMS=cpu`` jobs fail with
     "Multiprocess computations aren't implemented on the CPU backend".
-    Harmless on accelerator backends; tolerated missing on jax versions
-    that predate (or postdate) the option name."""
+    Harmless on accelerator backends."""
     import jax
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — option unknown on this jax version
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 @dataclass(frozen=True)
@@ -97,7 +93,7 @@ class DistributedContext:
         """1-D mesh with exactly ONE device per host, ordered by host id —
         the mesh the cross-host eval-stat reduction runs over (each host
         contributes one padded row; the collective crosses process
-        boundaries, unlike ``compat.local_device_mesh``'s local subset)."""
+        boundaries, unlike ``meshes.local_device_mesh``'s local subset)."""
         per_host: dict = {}
         for d in self.global_devices:
             per_host.setdefault(d.process_index, d)

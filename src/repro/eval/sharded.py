@@ -189,7 +189,7 @@ def _mesh_gather_fn(n_shards: int, axis_name: str):
 
     from repro.distributed import collectives as C
     from repro.distributed import sharding as shd
-    from repro.distributed.compat import local_device_mesh
+    from repro.distributed.meshes import local_device_mesh
 
     mesh = local_device_mesh(n_shards, axis_name)
     rules = shd.default_rules(mesh)
@@ -487,13 +487,17 @@ def evaluate_detector_sharded(
     shard count (per-image outputs are bitwise invariant to batch grouping:
     integer-domain conv accumulation plus elementwise float stages).
 
+    Each owned shard's forward runs on its own local device (the j-th
+    owned shard on ``ctx.local_devices[j % n_local]``), so on a host with
+    one device per shard the shards' forwards occupy every chip.
+
     Multi-controller: process ``i`` walks ONLY its owned shards
     ``i, i+P, ...`` (``ctx.owned_shards``) — forward work scales with
     1/n_hosts wall-clock — and the reduce crosses processes through the
     context's stripe mesh; every host returns the same full report.
     ``eval_cfg`` defaults to one shard per host; an uneven
     ``n_shards % n_hosts`` raises (``ctx.validate_shard_count``)."""
-    import jax.numpy as jnp
+    import jax
 
     from repro.distributed import runtime
 
@@ -509,7 +513,9 @@ def evaluate_detector_sharded(
     if cap is not None:
         n_images = min(n_images, cap)
     stats = []
-    for s in ctx.owned_shards(eval_cfg.n_shards):
+    devices = ctx.local_devices
+    for j, s in enumerate(ctx.owned_shards(eval_cfg.n_shards)):
+        device = devices[j % len(devices)]
         images, gts = source.eval_set(
             n_images, split=split, hw=cfg.input_hw, grid_div=grid_div(cfg),
             num_anchors=cfg.num_anchors, num_classes=cfg.num_classes,
@@ -518,7 +524,9 @@ def evaluate_detector_sharded(
         idx = sd.eval_shard_indices(n_images, s, eval_cfg.n_shards)
         preds: list = []
         for i in range(0, len(images), eval_cfg.batch):
-            dets, _ = det.detect(jnp.asarray(images[i : i + eval_cfg.batch]))
+            dets, _ = det.detect(
+                jax.device_put(images[i : i + eval_cfg.batch], device)
+            )
             preds.extend(dm.detections_to_predictions(dets))
         stats.append(
             match_stats(

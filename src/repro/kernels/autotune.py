@@ -257,6 +257,7 @@ def candidates(shape: LayerShape) -> list[TileConfig]:
             bpg = mr * mc
             vmem = (
                 shape.t_in * bpg * ph * pw * cin_p * in_bytes  # spike tile
+                + shape.t_in * bpg * ph * pw * cin_p * 4  # its f32 widening
                 + shape.kh * shape.kw * cin_p * kblk * 2  # maskp+decoded w
                 + bpg * shape.bh * shape.bw * kblk * (4 + 4 + shape.t_out)
             )

@@ -14,6 +14,19 @@ def auto_interpret(interpret: bool | None = None) -> bool:
     return interpret
 
 
+def refuse_compiled_decoder(kernel: str, interpret: bool) -> None:
+    """Raise unless ``interpret``: the in-kernel bitmask decoder (``cumsum``
+    over the mask bits, then a ``take`` gather of the packed values) has no
+    Mosaic lowering, so a kernel built on it runs in interpret mode only."""
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel}: the in-kernel bitmask decoder (cumsum + gather) has "
+            "no TPU lowering; it runs only with interpret=True. The compiled "
+            "inference path is the fused kernel with predecoded weights "
+            "(kernels.ops.fused_conv_bn_lif, predecode=True)."
+        )
+
+
 def count_pallas_calls(fn, *args, **kwargs) -> int:
     """Number of ``pallas_call`` equations in ``fn``'s jaxpr (recursing into
     nested sub-jaxprs: pjit, scan, cond bodies). This is the DISPATCH COUNT

@@ -21,7 +21,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .backend import auto_interpret
+from .backend import auto_interpret, refuse_compiled_decoder
 
 
 class PackedMatmulWeights(NamedTuple):
@@ -97,6 +97,7 @@ def bitmask_matmul_pallas(
     x: jax.Array, packed: PackedMatmulWeights, *, mblk: int = 256, interpret: bool | None = None
 ) -> jax.Array:
     interpret = auto_interpret(interpret)
+    refuse_compiled_decoder("bitmask_matmul_pallas", interpret)
     m, k = x.shape
     k_orig, n_orig = packed.shape
     assert k == k_orig, (k, k_orig)

@@ -13,7 +13,7 @@ between a conv `pallas_call`, an XLA tdBN, and an XLA LIF scan. This kernel
 collapses the full per-layer pipeline into ONE `pallas_call`:
 
     for t in range(T):                      # static unrolled, T ≤ 4
-        acc   = Σ_tap spikes_t ⋆ W[tap]     # int MXU dots, per-tap skip
+        acc   = Σ_tap spikes_t ⋆ W[tap]     # MXU dot per live tap, exact
         y     = acc * fxp_scale             # FXP8 dequant (once, exact)
         y     = c·((y − μ)·rsqrt(σ²+ε))·γ+β # tdBN inference affine
         v     = v·leak + y                  # LIF — v NEVER leaves VMEM
@@ -33,8 +33,8 @@ work.
 Bit-serial encode in one dispatch: the 8-bit encoding layer folds its 8 bit
 planes *into the input values* — Σ_b 2^b·conv(plane_b, W) = conv(Σ_b 2^b·
 plane_b, W) = conv(u8, W) by linearity over exact integers — so encode is
-ONE dispatch of this same kernel (in_bits=8 switches the dot to f32, exact
-for |acc| < 2^24). This is the TPU-native form of the paper's §III-C.2
+ONE dispatch of this same kernel (every dot accumulates integer values in
+f32, exact for |acc| < 2^24). This is the TPU-native form of the paper's §III-C.2
 bit-serial support: same datapath for both layer types, B folded above the
 channel loop. `benchmarks/kernel_bench.py` asserts the single-dispatch
 property by counting pallas_call equations in the trace and checks parity
@@ -45,12 +45,11 @@ inner, the paper's KTBC order, so compressed weights are decoded once per
 K-block and reused across every spatial tile and time step. Each grid step
 processes a MACRO-TILE of ``bpg = mrows·mcols`` spatial blocks (a whole
 row of blocks, or an r×c block group — the host layout in ops.py makes
-the group contiguous along the block axis): the gated product runs as
-``bpg//nbt`` MXU dots of ``nbt`` stacked blocks each, and the FXP rescale,
-tdBN affine and LIF update are vectorized across the WHOLE macro-tile.
-Large inputs are won here: at 96×128 a per-block grid is 256 steps whose
-per-step overhead (block fetch, interpret-loop iteration) dwarfs the
-arithmetic — macro-tiles collapse it to a handful of steps per K-block.
+the group contiguous along the block axis): the step runs ``bpg//nbt``
+groups of ``nbt`` stacked blocks, each group one MXU dot per live tap
+followed by the FXP rescale, tdBN affine and LIF update over its rows.
+Macro-tiles cut the number of grid steps, and so their fixed per-step
+cost, at large inputs.
 Blocks stay independent (each carries its own replicate-padded halo), so
 any macro shape is bit-exact with the one-block-per-step dispatch.
 ``(kblk, nbt, mrows×mcols)`` are the per-layer-shape autotuning knobs
@@ -65,7 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .backend import auto_interpret
+from .backend import auto_interpret, refuse_compiled_decoder
 
 # rows of the per-K-block affine parameter bundle (see _affine_bundle in
 # ops.py): FXP scale, tdBN mean, rsqrt(var+eps), gamma, beta
@@ -94,10 +93,9 @@ def _rounded(x: jax.Array) -> jax.Array:
 
 def _kernel(
     spikes_ref,  # VMEM (t_in, bpg, BH+2p, BW+2p, C) int8 (f32 for in_bits=8)
-    *refs,  # packed mode: maskp, vals, affine, v0, spk, mem, wdense scratch
-    #         predecoded mode: wdense, affine, v0, spk, mem (no scratch)
+    *refs,  # packed mode: maskp, vals, affine, v0, spk, mem, wdense + xs scratch
+    #         predecoded mode: wdense, affine, v0, spk, mem + xs scratch
     taps: int,
-    kh: int,
     kw: int,
     bh: int,
     bw: int,
@@ -105,26 +103,26 @@ def _kernel(
     nbt: int,  # blocks stacked per MXU dot; divides bpg
     t_in: int,
     t_out: int,
-    in_bits: int,
     tap_alive: tuple,  # taps with any nonzero weight (static, pack-time)
     bn_scale: float,  # alpha * threshold (tdBN), a trace-time constant
     threshold: float,
     leak: float,
     reset: str,
     predecode: bool,
-    conv_body: bool,  # interpret mode: one lax.conv instead of im2col ops
 ):
     if predecode:
         # decoder stage already ran (static weights decode once, at plan/
         # trace time — see fused_conv_bn_lif); the kernel consumes the
         # VMEM-resident dense K-block directly
-        wdense_ref, affine_ref, v0_ref, spk_ref, mem_ref = refs
+        wdense_ref, affine_ref, v0_ref, spk_ref, mem_ref, xs_ref = refs
     else:
-        maskp_ref, vals_ref, affine_ref, v0_ref, spk_ref, mem_ref, wdense_ref = refs
+        (maskp_ref, vals_ref, affine_ref, v0_ref, spk_ref, mem_ref,
+         wdense_ref, xs_ref) = refs
         nbg = pl.program_id(1)  # spatial group index (innermost)
 
         # ---- decode compressed weights once per K-block (paper: weights
-        # stay resident on-chip, reused across tiles and time steps) ----
+        # stay resident on-chip, reused across tiles and time steps).
+        # Interpret mode only (see backend.refuse_compiled_decoder). ----
         @pl.when(nbg == 0)
         def _decode():
             words = maskp_ref[0]  # (taps, C//8, KBLK) uint8
@@ -143,122 +141,79 @@ def _kernel(
             wdense_ref[...] = dense.reshape(taps, c8 * 8, kblk).astype(jnp.int8)
 
     kblk = wdense_ref.shape[-1]
-    m = bpg * bh * bw
-    acc_dtype = jnp.float32 if in_bits == 8 else jnp.int32
+    cin = spikes_ref.shape[-1]
+    rows = nbt * bh * bw  # membrane/output rows of one dot group
 
-    # ---- conv over the macro-tile: bpg//nbt MXU dots, each one
-    # (t_in·nbt·bh·bw, live·C)×(live·C, KBLK), covering every live tap and
-    # every input time step. The per-block im2col stacks the live taps'
-    # shifted windows along a patch axis; dead taps (every weight pruned —
-    # common for the 80%-pruned 3×3 kernels) are dropped from BOTH the
-    # patch matrix and the weight rows at TRACE time via ``tap_alive``
-    # (liveness is a pack-time property, so no runtime cond). Integer
-    # accumulation is order-independent, so folding the tap loop into the
-    # dot's reduction axis — and splitting the macro-tile into dot groups —
-    # is bit-exact with any per-tap, per-block summation. ----
-    spk_all = spikes_ref[...]  # one ref read; taps/groups slice the value
+    # The spike tile is widened to f32 once, in VMEM scratch: Mosaic slices
+    # f32 windows at any (row, column) offset, where int8 windows at the
+    # column offsets tap % kw are refused. Each window is cast to bf16 after
+    # slicing, so every dot is a single-pass bf16 MXU dot with f32
+    # accumulation: spikes {0,1}, u8 pixels and int8 weights are exact in
+    # bf16, and every partial sum stays below 2^24 (live·C·255·127 for
+    # encode), so the f32 accumulation is integer-exact in any order.
+    xs_ref[...] = spikes_ref[...].astype(jnp.float32)
     # predecoded input carries a leading (1,) K-block axis; scratch doesn't
-    wall = wdense_ref[0] if predecode else wdense_ref[...]
-    cin = spk_all.shape[-1]
-    ph_, pw_ = spk_all.shape[2], spk_all.shape[3]
-    if not tap_alive:
-        acc = jnp.zeros((t_in, m, kblk), acc_dtype)
-    elif conv_body:
-        # interpret mode runs the kernel body as XLA ops on CPU, where one
-        # native VALID conv over the WHOLE macro-tile beats the hand im2col
-        # (9 slices + stack + dot) by a wide margin — and is where the
-        # macro-tile pays off: one conv op per grid step regardless of bpg.
-        # Zero (pruned) taps contribute exact zeros, and integer-valued f32
-        # accumulation is order-independent, so this is bit-identical to
-        # the tap-sliced MXU dots used on hardware.
-        if kh == 1 and kw == 1:
-            # pointwise: no halo (ph == bh), the conv IS one channel dot —
-            # skip the conv op's window machinery entirely
-            acc = jax.lax.dot_general(
-                spk_all.reshape(t_in * m, cin).astype(jnp.float32),
-                wall.reshape(cin, kblk).astype(jnp.float32),
+    w_tap = [
+        (wdense_ref[0, t] if predecode else wdense_ref[t]).astype(jnp.bfloat16)
+        for t in tap_alive
+    ]
+
+    scale = affine_ref[0, 0:1, :]  # (1, KBLK) — FXP scale (row-broadcast)
+    mean = affine_ref[0, 1:2, :]
+    rinv = affine_ref[0, 2:3, :]  # rsqrt(var + eps), precomputed
+    gamma = affine_ref[0, 3:4, :]
+    beta = affine_ref[0, 4:5, :]
+
+    # ---- the macro-tile runs as bpg//nbt dot groups. Each group is one
+    # (t_in·nbt·bh·bw, C)×(C, KBLK) MXU dot per live tap (the shifted
+    # window of that tap, read straight from the widened tile), summed —
+    # then the FXP rescale, tdBN affine and LIF over T run on the group's
+    # rows and store them. Dead taps (every weight pruned — common for the
+    # 80%-pruned 3×3 kernels) are dropped at TRACE time via ``tap_alive``
+    # (liveness is a pack-time property, so no runtime cond). Every step
+    # after the dot is element-wise, so running it per group is
+    # bit-identical to running it over the whole macro-tile. ----
+    for g0 in range(0, bpg, nbt):  # static unroll: bpg//nbt dot groups
+        acc = jnp.zeros((t_in * rows, kblk), jnp.float32)
+        for j, tap in enumerate(tap_alive):
+            dy, dx = tap // kw, tap % kw
+            win = xs_ref[:, g0 : g0 + nbt, dy : dy + bh, dx : dx + bw, :]
+            acc = acc + jax.lax.dot_general(
+                win.reshape(t_in * rows, cin).astype(jnp.bfloat16),
+                w_tap[j],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ).reshape(t_in, m, kblk)
-        else:
-            x = spk_all.reshape(t_in * bpg, ph_, pw_, cin)
-            acc = jax.lax.conv_general_dilated(
-                x.astype(jnp.float32),
-                wall.reshape(kh, kw, cin, kblk).astype(jnp.float32),
-                window_strides=(1, 1),
-                padding="VALID",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            ).reshape(t_in, m, kblk)
-    else:
-        w = wall if len(tap_alive) == taps else jnp.stack([wall[t] for t in tap_alive])
-        w = w.reshape(len(tap_alive) * cin, kblk)
-        if in_bits == 8:
-            # multibit u8 input: f32 MXU dot — exact while live·C·255·127
-            # < 2^24 (the u8 encode layer has C≤8, far inside the bound)
-            w = w.astype(jnp.float32)
-        groups = []
-        for g0 in range(0, bpg, nbt):  # static unroll: bpg//nbt dot groups
-            blk = jax.lax.slice(
-                spk_all, (0, g0, 0, 0, 0), (t_in, g0 + nbt, ph_, pw_, cin)
             )
-            wins = [
-                jax.lax.slice(
-                    blk,
-                    (0, 0, tap // kw, tap % kw, 0),
-                    (t_in, nbt, tap // kw + bh, tap % kw + bw, cin),
-                )
-                for tap in tap_alive
-            ]
-            # (t_in, nbt, bh, bw, live, C) → rows ordered exactly like the
-            # membrane/output layout, cols ordered [tap, c] like wdense rows
-            patches = jnp.stack(wins, axis=-2)
-            s = patches.reshape(t_in, nbt * bh * bw, len(tap_alive) * cin)
-            groups.append(
-                jax.lax.dot_general(
-                    s,
-                    w,
-                    (((2,), (0,)), ((), ())),
-                    preferred_element_type=acc_dtype,
-                )
-            )
-        acc = groups[0] if len(groups) == 1 else jnp.concatenate(groups, axis=1)
 
-    scale = affine_ref[0, 0]  # (KBLK,) — FXP scale (scalar, row-broadcast)
-    mean = affine_ref[0, 1]
-    rinv = affine_ref[0, 2]  # rsqrt(var + eps), precomputed (deterministic)
-    gamma = affine_ref[0, 3]
-    beta = affine_ref[0, 4]
+        # FXP rescale then the tdBN inference affine — op-for-op the
+        # unfused core.plan executor + core.lif.tdbn_apply(training=False).
+        # _rounded pins every product that feeds an add/sub — see its
+        # docstring: without it XLA contracts mul+add into FMAs, a silent
+        # 1-ulp drift that can flip spikes sitting exactly at threshold.
+        y_all = _rounded(acc * scale)
+        x_hat = _rounded((y_all - mean) * rinv)
+        drives = _rounded((bn_scale * x_hat) * gamma) + beta
 
-    # FXP rescale then the tdBN inference affine — op-for-op the unfused
-    # core.plan executor + core.lif.tdbn_apply(training=False); element-wise,
-    # so applying it to the stacked (t_in·m, KBLK) drive is bit-identical.
-    # _rounded pins every product that feeds an add/sub — see its docstring:
-    # without it XLA contracts mul+add into FMAs, a silent 1-ulp drift that
-    # can flip spikes sitting exactly at threshold.
-    # vectorized across the whole macro-tile: one element-wise chain over
-    # (t_in, bpg·bh·bw, KBLK), however many dot groups produced the drive
-    y_all = _rounded(acc.astype(jnp.float32) * scale)
-    x_hat = _rounded((y_all - mean) * rinv)
-    drives = _rounded((bn_scale * x_hat) * gamma) + beta
-
-    v = v0_ref[...].reshape(m, kblk)
-    for t in range(t_out):  # T ≤ 4: unrolled, v stays in VREGs/VMEM
-        # mixed time steps (in_T=1 → out_T=T): one conv drive, T LIF steps
-        y = drives[0] if t_in == 1 else drives[t]
-        v = _rounded(v * leak) + y
-        spiked = v >= threshold
-        spk_ref[t] = spiked.reshape(bpg, bh, bw, kblk).astype(jnp.int8)
-        if reset == "soft":
-            # reset by subtraction: where(s, v−θ, v) ≡ v − s·θ for
-            # s ∈ {0,1} (s·θ is exactly 0 or θ, so one subtraction either
-            # way — bit-identical to core.lif.lif_step's soft branch)
-            v = jnp.where(spiked, v - threshold, v)
-        else:
-            # hard reset: where(s, 0, v) ≡ v·(1−s) for s ∈ {0,1} (no
-            # arithmetic → no rounding, so no _rounded barrier needed;
-            # ±0.0 both propagate as exact zero through v·leak + y)
-            v = jnp.where(spiked, 0.0, v)
-    mem_ref[...] = v.reshape(bpg, bh, bw, kblk)
+        r0 = g0 * bh * bw
+        v = v0_ref[r0 : r0 + rows, :]
+        for t in range(t_out):  # T ≤ 4: unrolled, v stays in VREGs/VMEM
+            # mixed time steps (in_T=1 → out_T=T): one conv drive, T LIF steps
+            y = drives[0:rows] if t_in == 1 else drives[t * rows : (t + 1) * rows]
+            v = _rounded(v * leak) + y
+            spiked = v >= threshold
+            spk_ref[t, r0 : r0 + rows, :] = spiked.astype(jnp.int8)
+            if reset == "soft":
+                # reset by subtraction: where(s, v−θ, v) ≡ v − s·θ for
+                # s ∈ {0,1} (s·θ is exactly 0 or θ, so one subtraction
+                # either way — bit-identical to core.lif.lif_step's soft
+                # branch)
+                v = jnp.where(spiked, v - threshold, v)
+            else:
+                # hard reset: where(s, 0, v) ≡ v·(1−s) for s ∈ {0,1} (no
+                # arithmetic → no rounding, so no _rounded barrier needed;
+                # ±0.0 both propagate as exact zero through v·leak + y)
+                v = jnp.where(spiked, 0.0, v)
+        mem_ref[r0 : r0 + rows, :] = v
 
 
 def fused_pipeline_pallas(
@@ -266,7 +221,7 @@ def fused_pipeline_pallas(
     maskp: jax.Array | None,  # (KB, taps, C//8, KBLK) uint8 (packed mode)
     vals: jax.Array | None,  # (KB, VPAD) int8 (packed mode)
     affine: jax.Array,  # (KB, AFFINE_ROWS, KBLK) f32
-    v0_blocks: jax.Array,  # (NB, BH, BW, KB*KBLK) f32
+    v0_rows: jax.Array,  # (NB·BH·BW, KB*KBLK) f32
     *,
     kh: int,
     kw: int,
@@ -286,24 +241,29 @@ def fused_pipeline_pallas(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One fused dispatch for a whole layer. Returns
-    (spikes (t_out, NB, BH, BW, KB*KBLK) int8, membrane (NB, BH, BW, KB*KBLK) f32).
+    (spikes (t_out, NB·BH·BW, KB*KBLK) int8, membrane (NB·BH·BW, KB*KBLK) f32),
+    rows ordered (block, bh, bw) — the same order as ``v0_rows``.
 
     Weights arrive either compressed (``maskp``/``vals`` — the kernel runs
     the bitmask decoder once per K-block, the paper's on-chip decode) or
     predecoded (``wdense`` — the decoder stage ran ahead of the kernel; for
     static inference weights it then runs once per COMPILE, not per frame).
-    Both modes compute bit-identically.
+    Both modes compute bit-identically in interpret mode; only the
+    predecoded mode lowers for the TPU, so compressed weights with
+    ``interpret=False`` raise.
 
     ``bpg`` spatial blocks — the macro-tile, e.g. mrows·mcols contiguous
     blocks of the block grid (callers order/pad the block axis so each
     macro group is contiguous and bpg divides NB) — are processed per grid
-    step; within a step the conv runs as ``bpg//nbt`` MXU dots of ``nbt``
+    step; within a step the conv runs as ``bpg//nbt`` groups of ``nbt``
     stacked blocks each. Grid order is K-blocks outer / macro-tiles inner
     so the decoded weight block is reused across every spatial tile and
     time step.
     """
     interpret = auto_interpret(interpret)
     predecode = wdense is not None
+    if not predecode:
+        refuse_compiled_decoder("fused_pipeline_pallas(maskp, vals)", interpret)
     t_in, nb_total, ph, pw, cin = spike_blocks.shape
     if bpg is None:
         bpg = nbt
@@ -319,25 +279,28 @@ def fused_pipeline_pallas(
     assert nb_total % bpg == 0, (nb_total, bpg)
     assert t_in == t_out or t_in == 1, (t_in, t_out)
     assert affine.shape == (kb_total, AFFINE_ROWS, kblk)
+    assert (spike_blocks.dtype == jnp.float32) == (in_bits == 8), (
+        spike_blocks.dtype, in_bits)
+    m = bpg * bh * bw  # membrane/output rows per grid step
 
+    xs_scratch = pltpu.VMEM((t_in, bpg, ph, pw, cin), jnp.float32)
     if predecode:
         w_specs = [pl.BlockSpec((1, taps, cin, kblk), lambda kb, nb: (kb, 0, 0, 0))]
         w_inputs = (wdense,)
-        scratch = []
+        scratch = [xs_scratch]
     else:
         w_specs = [
             pl.BlockSpec((1, taps, cin // 8, kblk), lambda kb, nb: (kb, 0, 0, 0)),
             pl.BlockSpec((1, vals.shape[1]), lambda kb, nb: (kb, 0)),
         ]
         w_inputs = (maskp, vals)
-        scratch = [pltpu.VMEM((taps, cin, kblk), jnp.int8)]
+        scratch = [pltpu.VMEM((taps, cin, kblk), jnp.int8), xs_scratch]
 
     grid = (kb_total, nb_total // bpg)  # K outer, macro inner → KTBC order
     spk, mem = pl.pallas_call(
         functools.partial(
             _kernel,
             taps=taps,
-            kh=kh,
             kw=kw,
             bh=bh,
             bw=bw,
@@ -345,31 +308,29 @@ def fused_pipeline_pallas(
             nbt=nbt,
             t_in=t_in,
             t_out=t_out,
-            in_bits=in_bits,
             tap_alive=tuple(tap_alive),
             bn_scale=bn_scale,
             threshold=threshold,
             leak=leak,
             reset=reset,
             predecode=predecode,
-            conv_body=bool(interpret),
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((t_in, bpg, ph, pw, cin), lambda kb, nb: (0, nb, 0, 0, 0)),
             *w_specs,
             pl.BlockSpec((1, AFFINE_ROWS, kblk), lambda kb, nb: (kb, 0, 0)),
-            pl.BlockSpec((bpg, bh, bw, kblk), lambda kb, nb: (nb, 0, 0, kb)),
+            pl.BlockSpec((m, kblk), lambda kb, nb: (nb, kb)),
         ],
         out_specs=[
-            pl.BlockSpec((t_out, bpg, bh, bw, kblk), lambda kb, nb: (0, nb, 0, 0, kb)),
-            pl.BlockSpec((bpg, bh, bw, kblk), lambda kb, nb: (nb, 0, 0, kb)),
+            pl.BlockSpec((t_out, m, kblk), lambda kb, nb: (0, nb, kb)),
+            pl.BlockSpec((m, kblk), lambda kb, nb: (nb, kb)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((t_out, nb_total, bh, bw, kb_total * kblk), jnp.int8),
-            jax.ShapeDtypeStruct((nb_total, bh, bw, kb_total * kblk), jnp.float32),
+            jax.ShapeDtypeStruct((t_out, nb_total * bh * bw, kb_total * kblk), jnp.int8),
+            jax.ShapeDtypeStruct((nb_total * bh * bw, kb_total * kblk), jnp.float32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-    )(spike_blocks, *w_inputs, affine, v0_blocks)
+    )(spike_blocks, *w_inputs, affine, v0_rows)
     return spk, mem

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .backend import auto_interpret
+from .backend import auto_interpret, refuse_compiled_decoder
 
 # paper tile: 32 wide × 18 tall = 576 PEs
 BLOCK_H = 18
@@ -119,9 +119,10 @@ def gated_one_to_all_pallas(
 ) -> jax.Array:
     """Run the kernel. Returns (NB, BH, BW, KB*KBLK) int32 partial sums.
 
-    ``interpret=None`` auto-detects: compiled Mosaic lowering on TPU,
-    interpreter mode on CPU/GPU backends."""
+    Interpret mode only: its in-kernel decoder has no TPU lowering, so
+    ``interpret=False`` (the auto-detected value on a TPU) raises."""
     interpret = auto_interpret(interpret)
+    refuse_compiled_decoder("gated_one_to_all_pallas", interpret)
     nb_total, ph, pw, cin = spike_blocks.shape
     kb_total, taps, c8, kblk_ = maskp.shape
     assert kblk_ == kblk and taps == kh * kw and c8 * 8 == cin

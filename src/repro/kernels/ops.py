@@ -356,7 +356,7 @@ def _dispatch_fused(
     maskp,
     vals,
     affine,
-    v0_blocks,
+    v0_rows,
     wdense,
     *,
     kh,
@@ -385,7 +385,7 @@ def _dispatch_fused(
         maskp,
         vals,
         affine,
-        v0_blocks,
+        v0_rows,
         kh=kh,
         kw=kw,
         bh=bh,
@@ -403,9 +403,10 @@ def _dispatch_fused(
         wdense=wdense,
         interpret=interpret,
     )
-    spk = _unblock(spk.astype(jnp.float32), n=batch, h=out_h, w=out_w,
-                   mr=mr, mc=mc)
-    mem = _unblock(mem, n=batch, h=out_h, w=out_w, mr=mr, mc=mc)
+    blocks = (-1, bh, bw, mem.shape[-1])
+    spk = _unblock(spk.reshape((t_out,) + blocks).astype(jnp.float32),
+                   n=batch, h=out_h, w=out_w, mr=mr, mc=mc)
+    mem = _unblock(mem.reshape(blocks), n=batch, h=out_h, w=out_w, mr=mr, mc=mc)
     return spk[..., :kout], mem[..., :kout]
 
 
@@ -502,11 +503,11 @@ def fused_conv_bn_lif(
     if v0 is None:
         # cold start at v_init (conversion's θ/2 rounding trick); padded
         # channels/blocks get it too but are sliced away on the way out
-        v0b = jnp.full((nb, bh, bw, kp), v_init, jnp.float32)
+        v0b = jnp.full((nb * bh * bw, kp), v_init, jnp.float32)
     else:
         v0b = _block_layout_nohalo(
             v0.astype(jnp.float32), bh=bh, bw=bw, cpad=kp, mr=mrows, mc=mcols
-        )
+        ).reshape(nb * bh * bw, kp)
     return _dispatch_fused(
         blocks,
         None if predecode else pw.maskp,

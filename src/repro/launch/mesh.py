@@ -12,7 +12,7 @@ whole job."""
 from __future__ import annotations
 
 from repro.distributed import runtime
-from repro.distributed.compat import make_mesh
+from repro.distributed.meshes import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False, ctx=None):

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import ALL_IDS, get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import zoo
 from repro.serve import AdmissionPolicy, Engine, FrameRequest, Request
 
@@ -179,9 +180,13 @@ def _serve_detector(cfg, args):
                     "served-detections mAP does not match "
                     "harness.evaluate_detector on the restored weights"
                 )
+    return eng, done
 
 
 def main(argv=None):
+    """Serve per the command line. For ``--arch snn-det`` returns the
+    ``(engine, finished requests)`` pair, so a caller can check what was
+    served (``chip_smoke.py`` compares it with a dense replay)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ALL_IDS), required=True)
     ap.add_argument("--requests", type=int, default=8)
@@ -223,12 +228,13 @@ def main(argv=None):
                          "mesh-sharded mAP reduction (with --eval-map)")
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = smoke_config(cfg)
     if args.arch == "snn-det":
-        _serve_detector(cfg, args)
+        return _serve_detector(cfg, args)
     else:
         _serve_lm(cfg, args)
 

@@ -30,6 +30,7 @@ from repro.configs import ARCH_IDS, get_config, smoke_config
 from repro.data import lm_data
 from repro.distributed import runtime
 from repro.distributed.sharding import default_rules, tree_shardings_for, use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import zoo
 from repro.train import ft
@@ -64,6 +65,7 @@ def main(argv=None):
                                  process_id=args.process_id)
     else:
         ctx = runtime.get_context()
+    enable_compile_cache()
     if args.batch % ctx.n_hosts != 0:
         raise SystemExit(
             f"--batch {args.batch} is the GLOBAL batch and must divide over "

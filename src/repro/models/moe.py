@@ -222,7 +222,7 @@ def moe_mlp_ep(x: jax.Array, p: dict, cfg: LMConfig):
     t_l = t // max(n_data, 1)
     C = max(int(np.ceil(cfg.top_k * t_l / cfg.n_experts * cfg.capacity_factor)), 8)
 
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(x2d, router_w, wi, wg, wo, shared):

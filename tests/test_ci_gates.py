@@ -1,12 +1,9 @@
-"""The CI gate scripts (scripts/check_crossover.py, the step-summary
-writer in scripts/bench_regression.py) as units: the crossover gate is
-what keeps the macro-tiled pallas win at large inputs from silently
-regressing, so its skip/tolerance/parity edges need pinning."""
+"""The step-summary writer of the CI benchmark gate
+(scripts/bench_regression.py) as a unit."""
 from __future__ import annotations
 
 import importlib.util
 import os
-import sys
 
 import pytest
 
@@ -22,67 +19,8 @@ def _load(name):
 
 
 @pytest.fixture(scope="module")
-def crossover():
-    return _load("check_crossover")
-
-
-@pytest.fixture(scope="module")
 def bench_regression():
     return _load("bench_regression")
-
-
-def _payload(dense_s, pallas_s, *, diff=0.0):
-    return {"executors": {
-        "dense": {"wall_s": dense_s, "max_abs_diff_vs_dense": 0.0},
-        "gated": {"wall_s": dense_s, "max_abs_diff_vs_dense": diff},
-        "pallas": {"wall_s": pallas_s, "max_abs_diff_vs_dense": diff},
-    }}
-
-
-class TestCrossoverGate:
-    def test_pallas_faster_passes(self, crossover):
-        ok, msg = crossover.check(_payload(0.008, 0.0078),
-                                  tolerance=0.05, min_seconds=0.001)
-        assert ok and "crossover holds" in msg
-
-    def test_pallas_within_tolerance_passes(self, crossover):
-        ok, _ = crossover.check(_payload(0.008, 0.0082),
-                                tolerance=0.05, min_seconds=0.001)
-        assert ok  # 1.025x < 1.05x headroom
-
-    def test_pallas_slower_fails(self, crossover):
-        ok, msg = crossover.check(_payload(0.0079, 0.0108),  # the pre-
-                                  tolerance=0.05, min_seconds=0.001)
-        assert not ok and "slower than dense" in msg  # macro-tile state
-
-    def test_sub_floor_walls_skip(self, crossover):
-        ok, msg = crossover.check(_payload(0.0004, 0.0009),
-                                  tolerance=0.05, min_seconds=0.001)
-        assert ok and "skipped" in msg
-
-    def test_nonzero_diff_fails_even_when_faster(self, crossover):
-        """A fast-but-wrong kernel must fail: bit-exactness is part of
-        the crossover contract, not a separate gate."""
-        ok, msg = crossover.check(_payload(0.008, 0.004, diff=1e-6),
-                                  tolerance=0.05, min_seconds=0.001)
-        assert not ok and "max_abs_diff_vs_dense" in msg
-
-    def test_missing_executor_fails(self, crossover):
-        ok, _ = crossover.check({"executors": {
-            "dense": {"wall_s": 0.008, "max_abs_diff_vs_dense": 0.0}}},
-            tolerance=0.05, min_seconds=0.001)
-        assert not ok
-
-    def test_cli_exit_codes(self, crossover, tmp_path, capsys):
-        import json
-        good = tmp_path / "good.json"
-        good.write_text(json.dumps(_payload(0.008, 0.0078)))
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps(_payload(0.0079, 0.0108)))
-        assert crossover.main(["--file", str(good)]) == 0
-        assert crossover.main(["--file", str(bad)]) == 1
-        assert crossover.main(["--file", str(tmp_path / "absent.json")]) == 1
-        capsys.readouterr()
 
 
 class TestStepSummary:

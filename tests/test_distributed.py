@@ -26,7 +26,7 @@ def test_int8_psum_and_hierarchical():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed import collectives as C
-        from repro.distributed.compat import make_mesh
+        from repro.distributed.meshes import make_mesh
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         x = jnp.arange(24, dtype=jnp.float32).reshape(4, 6) / 7.0
         with mesh:
@@ -47,7 +47,7 @@ def test_overlap_allgather_matmul():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed import collectives as C
-        from repro.distributed.compat import make_mesh
+        from repro.distributed.meshes import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 16))
         w = jax.random.normal(jax.random.PRNGKey(1), (16, 12))
@@ -65,7 +65,7 @@ def test_distributed_embedding_grads_sharded():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed import sharding as shd, embedding as de
-        from repro.distributed.compat import make_mesh
+        from repro.distributed.meshes import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         rules = shd.default_rules(mesh, fsdp=True)
         V, D, B, S = 32, 16, 4, 8
@@ -93,7 +93,7 @@ def test_kvops_seq_sharded_write():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed import sharding as shd, kvops
-        from repro.distributed.compat import make_mesh
+        from repro.distributed.meshes import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         rules = shd.default_rules(mesh)
         L_, B, S, KV, HD = 3, 2, 16, 2, 4

@@ -327,3 +327,88 @@ class TestBitmaskMatmulKernel:
         dense_bytes = w.size * 4
         # f32 values: 0.2*4 bytes + 1/8 mask byte per element ≈ 0.93/4 of dense
         assert packed.compressed_bytes < 0.35 * dense_bytes
+
+
+class TestCompiledDecoderRefused:
+    """The in-kernel bitmask decoders have no TPU lowering: asked for a
+    compiled kernel (``interpret=False``) they raise, before tracing any
+    kernel, instead of returning something else."""
+
+    @pytest.mark.parametrize("kernel", ["gated_conv", "fused_packed", "bitmask_matmul"])
+    def test_raises_without_interpret(self, kernel):
+        w = _sparse_int8_weights(1, 3, 3, 8, 8, 0.3)
+        pw = ops.pack_conv_weights(w, kblk=8)
+        spikes = jnp.ones((1, 12, 16, 8), jnp.float32)
+        with pytest.raises(NotImplementedError, match="no TPU lowering"):
+            if kernel == "gated_conv":
+                ops.gated_conv(spikes.astype(jnp.int8), pw, bh=6, bw=8,
+                               interpret=False)
+            elif kernel == "fused_packed":
+                affine = ops.affine_bundle(
+                    pw, jnp.float32(1.0), *(jnp.ones(8, jnp.float32),) * 4)
+                ops.fused_conv_bn_lif(
+                    spikes[None], pw, affine, v0=None, out_t=1, in_bits=1,
+                    bn_scale=0.5, threshold=0.5, leak=0.25, bh=6, bw=8,
+                    predecode=False, interpret=False)
+            else:
+                packed = pack_weights(np.eye(64, dtype=np.float32), kblk=64, nblk=64)
+                ops.bitmask_matmul(jnp.ones((8, 64)), packed, interpret=False)
+
+
+class TestFusedKernelVsDenseOracle:
+    """The fused kernel's one body (tap-shifted windows, one MXU dot per
+    live tap) in interpret mode, against the dense executor's unfused
+    conv → tdBN → LIF layer — both through ``snn_yolo._conv_bn_act``, both
+    jitted, as the detector's forward runs them. Spikes and membranes must
+    be bit-identical for every layer kind the detector has."""
+
+    @pytest.mark.parametrize(
+        "kh,cin,kout,t_in,t_out,in_bits,tile",
+        [
+            (3, 8, 16, 3, 3, 1, (16, 1, 1, 1)),  # 3×3, one block per step
+            (3, 16, 24, 1, 3, 1, (8, 2, 2, 2)),  # 3×3, T 1→3, 2×2 macro, 3 K-blocks
+            (1, 24, 16, 3, 3, 1, (16, 4, 2, 2)),  # 1×1, macro-tile, one dot
+            (3, 3, 16, 1, 1, 8, (16, 1, 1, 4)),  # encode: u8 input, 1×4 macro
+            (3, 3, 8, 1, 3, 8, (8, 2, 2, 2)),  # rate-coded encode, T 1→3
+        ],
+    )
+    def test_bit_identical_to_dense_layer(self, kh, cin, kout, t_in, t_out,
+                                          in_bits, tile):
+        import dataclasses
+
+        from repro.core import plan as cplan
+        from repro.kernels.autotune import TileConfig
+        from repro.models import snn_yolo as sy
+
+        rng = np.random.default_rng(kh * 100 + cin + t_in)
+        cfg = sy.SNNDetConfig(input_hw=(24, 32), block_hw=(6, 8),
+                              use_block_conv=True, conv_exec="pallas")
+        w = rng.normal(size=(kh, kh, cin, kout)).astype(np.float32)
+        w[rng.random(w.shape) < 0.6] = 0.0  # pruned, whole taps may die
+        name = "encode" if in_bits == 8 else "layer"
+        lp = cplan.build_layer_plan(name, jnp.asarray(w), in_bits=in_bits,
+                                    tile=TileConfig(*tile))
+        plan = cplan.DetectorPlan(layers={name: lp}, block_hw=cfg.block_hw)
+        layer_p = {"w": jnp.asarray(w),
+                   "gamma": jnp.asarray(rng.normal(size=kout), jnp.float32),
+                   "beta": jnp.asarray(rng.normal(size=kout), jnp.float32)}
+        layer_s = {"mean": jnp.asarray(rng.normal(size=kout) * 4, jnp.float32),
+                   "var": jnp.asarray(rng.random(kout) * 16 + 1, jnp.float32),
+                   "count": jnp.zeros((), jnp.int32)}
+        if in_bits == 8:  # images on the u8 grid, the encode layer's input
+            x_t = rng.integers(0, 256, (t_in, 2, 24, 32, cin)) / 255.0
+        else:
+            x_t = rng.integers(0, 2, (t_in, 2, 24, 32, cin))
+        x_t = jnp.asarray(x_t, jnp.float32)
+
+        def layer(c):
+            return jax.jit(lambda x: sy._conv_bn_act(
+                x, layer_p, layer_s, c, False, out_t=t_out, name=name, plan=plan
+            ))
+
+        spk_p, _, mem_p = layer(cfg)(x_t)
+        spk_d, _, mem_d = layer(dataclasses.replace(cfg, conv_exec="dense"))(x_t)
+        assert spk_p.shape == (t_out, 2, 24, 32, kout)
+        assert 0 < float(spk_d.mean()) < 1  # spikes neither silent nor saturated
+        np.testing.assert_array_equal(np.asarray(spk_p), np.asarray(spk_d))
+        np.testing.assert_array_equal(np.asarray(mem_p), np.asarray(mem_d))

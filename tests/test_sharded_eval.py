@@ -279,3 +279,38 @@ def test_mesh_gather_requires_devices():
             preds, gts, num_classes=NUM_CLASSES,
             eval_cfg=se.ShardedEvalConfig(n_shards=4, use_device_mesh=True),
         )
+
+
+def test_detector_shards_forward_on_their_own_devices():
+    """Each owned shard's forward runs on its own local device: shard s on
+    device s, round robin once the shards outnumber the devices. A stub
+    detector records where its input frames live."""
+    out = _run("""
+        import numpy as np, jax
+        from repro.configs import get_config, smoke_config
+        from repro.eval import sharded as se
+        from repro.models.postprocess import Detections
+
+        class StubDetector:
+            cfg = smoke_config(get_config("snn-det"))
+
+            def __init__(self):
+                self.devices = []
+
+            def detect(self, frames):
+                self.devices.append(sorted(d.id for d in frames.devices()))
+                z = np.zeros((frames.shape[0], 1))
+                return Detections(np.zeros(z.shape + (4,), np.float32),
+                                  z.astype(np.float32), z.astype(np.int32),
+                                  z.astype(bool)), None
+
+        det = StubDetector()
+        for k, want in ((4, [0, 1, 2, 3]), (10, list(range(8)) + [0, 1])):
+            det.devices.clear()
+            rep = se.evaluate_detector_sharded(
+                det, n_images=10, eval_cfg=se.ShardedEvalConfig(n_shards=k))
+            assert rep["n_images"] == 10 and rep["n_shards"] == k
+            assert det.devices == [[d] for d in want], det.devices
+        print("SHARD_DEVICES_OK")
+    """)
+    assert "SHARD_DEVICES_OK" in out
