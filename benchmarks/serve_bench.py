@@ -5,16 +5,16 @@ Two sections, both written into ``BENCH_serve.json``:
 * ``executors`` — for each conv executor, compiles the smoke-scale detector
   once, serves a fixed set of concurrent :class:`FrameRequest` streams
   through the Engine's continuous-batching loop, and records throughput
-  (frames/sec) plus per-tick latency percentiles (p50/p95/p99 of one
-  megabatched step, jit warmup excluded). Also asserts that every
+  (frames/sec) plus per-tick latency percentiles (p50/p95 of the engine
+  tracer's ``tick`` span, jit warm-up included). Also asserts that every
   executor's served raw heads match the dense executor's exactly (the
   compile-once path may not drift from the oracle under slot batching /
   membrane carryover).
 
 * ``load`` — the load generator: N fully-resident concurrent streams
   (``--streams 64 256 [1024]``) megabatched through one engine tick per
-  frame, recording p50/p95/p99 tick latency, aggregate frames/s and
-  per-stream fps (the paper's target is 29 fps/stream sustained across
+  frame, recording p50/p95 tick latency and the tracer's summary,
+  aggregate frames/s and per-stream fps (the paper's target is 29 fps/stream sustained across
   >= 64 streams). A sample of served streams is asserted BIT-IDENTICAL to
   an independent per-stream DetectorSession replay — megabatching, row
   remapping and the double-buffered upload may not change a single bit.
@@ -41,15 +41,16 @@ def _run_executors(base, params, bn, streams, *, requests, slots, frames):
     import dataclasses as dc
 
     from repro.models import snn_yolo as sy
-    from repro.serve import Engine, FrameRequest
-    from repro.serve.detector import step_latency_ms
+    from repro.serve import DetectorEngineCore, Engine, FrameRequest
+    from repro.serve.trace import Tracer
 
     out = {}
     served_heads = {}
     for ex in EXECUTORS:
         cfg = dc.replace(base, conv_exec=ex)
         det = sy.compile_detector(cfg, params, bn)
-        eng = Engine(det, n_slots=slots)
+        eng = Engine(core=DetectorEngineCore(det, n_slots=slots,
+                                             tracer=Tracer(enabled=True)))
         reqs = [FrameRequest(rid=r, frames=s) for r, s in enumerate(streams)]
         for fr in reqs:
             eng.submit(fr)
@@ -63,16 +64,18 @@ def _run_executors(base, params, bn, streams, *, requests, slots, frames):
             for rid in served_heads[ex]
         )
         assert diff <= PARITY_ATOL, f"{ex} served heads diverge from dense: {diff}"
+        tick = eng.tracer.summary()["spans"]["tick"]
         out[ex] = {
             "frames_per_s": requests * frames / dt,
             "wall_s": dt,
-            **step_latency_ms(eng.core.step_wall),
+            "tick_p50_ms": tick["p50_ms"],
+            "tick_p95_ms": tick["p95_ms"],
             "max_abs_diff_vs_dense": diff,
         }
         r = out[ex]
         print(f"  {ex:7s} {r['frames_per_s']:7.1f} frames/s  "
-              f"p50 {r['step_p50_ms']:6.1f}ms  p95 {r['step_p95_ms']:6.1f}ms  "
-              f"p99 {r['step_p99_ms']:6.1f}ms  max|Δ| vs dense {diff:.2e}")
+              f"tick p50 {r['tick_p50_ms']:6.1f}ms  p95 {r['tick_p95_ms']:6.1f}ms  "
+              f"max|Δ| vs dense {diff:.2e}")
     return out
 
 
@@ -80,16 +83,17 @@ def _run_load(base, params, bn, *, n_streams, frames, parity_streams):
     import dataclasses as dc
 
     from repro.models import snn_yolo as sy
-    from repro.serve import AdmissionPolicy, Engine, FrameRequest
-    from repro.serve.detector import step_latency_ms, synth_streams
+    from repro.serve import AdmissionPolicy, DetectorEngineCore, Engine, FrameRequest
+    from repro.serve.detector import synth_streams
+    from repro.serve.trace import Tracer
 
     cfg = dc.replace(base, conv_exec=LOAD_EXECUTOR)
     det = sy.compile_detector(cfg, params, bn)
     rng = np.random.default_rng(1234 + n_streams)
     streams = synth_streams(rng, n_streams, frames, base.input_hw)
     eng = Engine(
-        det,
-        n_slots=n_streams,  # fully resident: true N-way concurrency
+        # fully resident: true N-way concurrency
+        core=DetectorEngineCore(det, n_slots=n_streams, tracer=Tracer(enabled=True)),
         admission=AdmissionPolicy(max_queue=n_streams),
     )
     reqs = [FrameRequest(rid=r, frames=s) for r, s in enumerate(streams)]
@@ -112,23 +116,23 @@ def _run_load(base, params, bn, *, n_streams, frames, parity_streams):
                 f"solo DetectorSession replay by {diff}"
             )
 
-    lat = step_latency_ms(eng.core.step_wall)
+    summary = eng.tracer.summary()
+    tick = summary["spans"]["tick"]
     rec = {
         "n_streams": n_streams,
         "frames_per_stream": frames,
         "wall_s": dt,
         "frames_per_s": n_streams * frames / dt,
         "per_stream_fps": frames / dt,
-        "tick_p50_ms": lat["step_p50_ms"],
-        "tick_p95_ms": lat["step_p95_ms"],
-        "tick_p99_ms": lat["step_p99_ms"],
+        "tick_p50_ms": tick["p50_ms"],
+        "tick_p95_ms": tick["p95_ms"],
+        "tracer": summary,
         "parity_streams": parity_streams,
         "max_abs_diff_vs_session": 0.0,
     }
     print(f"  load {n_streams:5d} streams  {rec['frames_per_s']:8.1f} frames/s "
           f"({rec['per_stream_fps']:6.2f} fps/stream)  tick p50 "
-          f"{rec['tick_p50_ms']:7.1f}ms  p95 {rec['tick_p95_ms']:7.1f}ms  "
-          f"p99 {rec['tick_p99_ms']:7.1f}ms")
+          f"{rec['tick_p50_ms']:7.1f}ms  p95 {rec['tick_p95_ms']:7.1f}ms")
     return rec
 
 

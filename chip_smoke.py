@@ -155,7 +155,6 @@ def serve_phase(*, full_config: bool = True, requests: int = 6, frames: int = 3,
     kernels and compare the served outputs with the dense replay."""
     from repro.kernels import autotune
     from repro.launch import serve
-    from repro.serve.detector import step_latency_ms
 
     argv = ["--arch", "snn-det", "--conv-exec", "pallas", "--requests",
             str(requests), "--frames", str(frames), "--slots", str(slots)]
@@ -170,8 +169,7 @@ def serve_phase(*, full_config: bool = True, requests: int = 6, frames: int = 3,
         "frames_served": sum(len(r.out) for r in done),
         "serve_s": serve_s,
         "first_tick_s": core.step_wall[0],
-        **{k.replace("_ms", "_s"): v / 1e3
-           for k, v in step_latency_ms(core.step_wall).items()},
+        "tracer": eng.tracer.summary(),
         "fused_layers": len(autotune.detector_layer_shapes(det.cfg)),
         "tpu_custom_calls": count_kernels(det, core.cap),
     }

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import json
 import time
 
 import jax
@@ -68,7 +69,8 @@ def _serve_detector(cfg, args):
     from repro.data import detection_datasets as dd
     from repro.eval import harness
     from repro.models import snn_yolo as sy
-    from repro.serve.detector import demo_weights, step_latency_ms, synth_streams
+    from repro.serve.detector import DetectorEngineCore, demo_weights, synth_streams
+    from repro.serve.trace import Tracer
 
     source = dd.parse_dataset_spec(args.dataset)
     if args.checkpoint:
@@ -93,7 +95,8 @@ def _serve_detector(cfg, args):
         det = harness.compile_eval_detector(cfg, params, bn)
     else:
         det = sy.compile_detector(cfg, params, bn)
-    eng = Engine(det, n_slots=args.slots, admission=_admission(args))
+    core = DetectorEngineCore(det, n_slots=args.slots, tracer=Tracer(enabled=True))
+    eng = Engine(core=core, admission=_admission(args))
     gts = None
     n_requests = args.requests
     if args.eval_map:
@@ -118,11 +121,13 @@ def _serve_detector(cfg, args):
     dt = time.time() - t0
     assert len(done) == n_requests - len(eng.rejected)
     total_frames = sum(len(r.out) for r in done)
-    lat = step_latency_ms(eng.core.step_wall)
+    summary = eng.tracer.summary()
+    tick = summary["spans"]["tick"]
     print(f"{args.arch}[{cfg.conv_exec}]: served {len(done)} streams "
           f"({total_frames} frames) in {dt:.1f}s — {total_frames/dt:.1f} frames/s, "
-          f"step p50 {lat['step_p50_ms']:.1f}ms p95 {lat['step_p95_ms']:.1f}ms "
-          f"p99 {lat['step_p99_ms']:.1f}ms")
+          f"tick p50 {tick['p50_ms']:.1f}ms p95 {tick['p95_ms']:.1f}ms "
+          f"(first tick included)")
+    print(f"  tracer: {json.dumps(summary)}")
     for r in sorted(done, key=lambda r: r.rid)[:3]:
         counts = [int(d.count) for d in r.out]
         print(f"  req {r.rid}: {len(r.out)} frames, detections/frame {counts}")

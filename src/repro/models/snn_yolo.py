@@ -294,7 +294,17 @@ def _activation(y_t, cfg: SNNDetConfig, *, v0=None):
     raise ValueError(cfg.mode)
 
 
-def _conv_bn_act(
+def _conv_bn_act(x_t, layer_p, layer_s, cfg, train, *, name, **kw):
+    """Conv (per time step) → tdBN → activation, under a named scope of the
+    layer's name: every device op of the layer (the fused kernel and its
+    own glue) carries the name in its op metadata, so a profiler trace
+    splits device time by layer. Metadata only; the numbers are those of
+    :func:`_conv_bn_act_body`."""
+    with jax.named_scope(name):
+        return _conv_bn_act_body(x_t, layer_p, layer_s, cfg, train, name=name, **kw)
+
+
+def _conv_bn_act_body(
     x_t, layer_p, layer_s, cfg, train, *, out_t=None, name=None, plan=None, v0=None,
     affine=None, taps=None, pool=False,
 ):
@@ -479,8 +489,11 @@ def forward(
         affine=aff.get("encode"), taps=taps, pool=True,
     )
     aux["spikes"]["encode"] = s_t
+    n_pool = 0
     if not pd:
-        s_t = _pool_t(s_t, cfg)
+        with jax.named_scope(f"pool{n_pool}"):
+            s_t = _pool_t(s_t, cfg)
+        n_pool += 1
 
     # --- conv block: in_T=1, out_T=full_t (mixed time steps) ---
     out_t = full_t if cfg.mixed_time else s_t.shape[0]
@@ -495,7 +508,9 @@ def forward(
     )
     aux["spikes"]["conv_block"] = s_t
     if not pd:
-        s_t = _pool_t(s_t, cfg)
+        with jax.named_scope(f"pool{n_pool}"):
+            s_t = _pool_t(s_t, cfg)
+        n_pool += 1
 
     # --- CSP basic blocks ---
     for i in range(len(cfg.stage_channels)):
@@ -516,31 +531,35 @@ def forward(
         )
         m, new_state[f"{name}/main_a"], new_mem[f"{name}/main_a"] = cba(m, f"{name}/main_a")
         m, new_state[f"{name}/main_b"], new_mem[f"{name}/main_b"] = cba(m, f"{name}/main_b")
-        cat = jnp.concatenate([m, short], axis=-1)
+        with jax.named_scope(f"{name}/concat"):
+            cat = jnp.concatenate([m, short], axis=-1)
         s_t, new_state[f"{name}/agg"], new_mem[f"{name}/agg"] = cba(
             cat, f"{name}/agg", pool=i < cfg.pooled_stages - 1
         )
         aux["spikes"][name] = s_t
         if i < cfg.pooled_stages - 1 and not pd:
-            s_t = _pool_t(s_t, cfg)
+            with jax.named_scope(f"pool{n_pool}"):
+                s_t = _pool_t(s_t, cfg)
+            n_pool += 1
 
     # --- output conv: accumulate membrane with no reset, average over T ---
-    y_t = _conv_t(s_t, params["head"], cfg, name="head", plan=plan)
-    if taps is not None:
-        taps["head"] = y_t
-    if cfg.mode == "snn":
-        head, new_mem["head"] = lifm.membrane_readout(
-            y_t, leak=cfg.leak, v0=mem.get("head"), return_final=True
-        )
-        if cfg.head_readout == "final":
-            # final membrane / T: every step weighted equally (the mean
-            # readout weights step t by (T−t+1)/T, biased against the
-            # late first-spikes of low-rate neurons)
-            head = new_mem["head"] / y_t.shape[0]
-    else:
-        head = jnp.mean(y_t, axis=0)
-    n, gh, gw, _ = head.shape
-    head = head.reshape(n, gh, gw, cfg.num_anchors, 5 + cfg.num_classes)
+    with jax.named_scope("head"):
+        y_t = _conv_t(s_t, params["head"], cfg, name="head", plan=plan)
+        if taps is not None:
+            taps["head"] = y_t
+        if cfg.mode == "snn":
+            head, new_mem["head"] = lifm.membrane_readout(
+                y_t, leak=cfg.leak, v0=mem.get("head"), return_final=True
+            )
+            if cfg.head_readout == "final":
+                # final membrane / T: every step weighted equally (the mean
+                # readout weights step t by (T−t+1)/T, biased against the
+                # late first-spikes of low-rate neurons)
+                head = new_mem["head"] / y_t.shape[0]
+        else:
+            head = jnp.mean(y_t, axis=0)
+        n, gh, gw, _ = head.shape
+        head = head.reshape(n, gh, gw, cfg.num_anchors, 5 + cfg.num_classes)
     return head, new_state, aux
 
 

@@ -38,6 +38,8 @@ from repro.core import plan as cplan
 from repro.core import pruning
 from repro.models import snn_yolo as sy
 from repro.models.postprocess import Detections, postprocess
+from repro.serve.trace import NULL as NULL_TRACER
+from repro.serve.trace import Tracer
 
 
 class StalePlanError(RuntimeError):
@@ -145,13 +147,14 @@ class CompiledDetector:
                 params, bn, frames, cfg_, train=False, plan=plan_, membrane=mem,
                 affines=affines_,
             )
-            dets = postprocess(
-                head,
-                self.anchors,
-                score_threshold=self.score_threshold,
-                iou_threshold=self.iou_threshold,
-                max_detections=self.max_detections,
-            )
+            with jax.named_scope("postprocess"):
+                dets = postprocess(
+                    head,
+                    self.anchors,
+                    score_threshold=self.score_threshold,
+                    iou_threshold=self.iou_threshold,
+                    max_detections=self.max_detections,
+                )
             return head, aux["membrane"], dets
 
         def _masked(params, bn, frames, mem, active, cold):
@@ -162,7 +165,8 @@ class CompiledDetector:
                 m = cold.reshape((-1,) + (1,) * (v.ndim - 1))
                 return jnp.where(m, jnp.zeros((), v.dtype), v)
 
-            mem0 = jax.tree_util.tree_map(blank, mem)
+            with jax.named_scope("mask"):
+                mem0 = jax.tree_util.tree_map(blank, mem)
             head, new_mem, dets = _step(params, bn, frames, mem0)
 
             # inactive rows are dead lanes in the megabatch: their compute
@@ -172,7 +176,9 @@ class CompiledDetector:
                 m = active.reshape((-1,) + (1,) * (new.ndim - 1))
                 return jnp.where(m, new, old)
 
-            return head, jax.tree_util.tree_map(keep, new_mem, mem0), dets
+            with jax.named_scope("mask"):
+                new_mem = jax.tree_util.tree_map(keep, new_mem, mem0)
+            return head, new_mem, dets
 
         self._step = jax.jit(_step)
         self._masked_step_fn = jax.jit(_masked)
@@ -346,17 +352,6 @@ def synth_streams(rng, n_streams: int, n_frames: int, hw) -> list:
     ]
 
 
-def step_latency_ms(step_wall: list) -> dict:
-    """p50/p95/p99 of the engine's per-tick session-step latency, first
-    tick (jit warmup) excluded."""
-    wall = np.asarray(step_wall[1:] or step_wall)
-    return {
-        "step_p50_ms": float(np.percentile(wall, 50) * 1e3),
-        "step_p95_ms": float(np.percentile(wall, 95) * 1e3),
-        "step_p99_ms": float(np.percentile(wall, 99) * 1e3),
-    }
-
-
 # ------------------------------------------------------------ engine core --
 
 
@@ -407,8 +402,11 @@ class DetectorEngineCore:
     """
 
     def __init__(self, det: CompiledDetector, *, n_slots: int = 8,
-                 min_bucket: int = 8):
+                 min_bucket: int = 8, tracer: Optional[Tracer] = None):
         self.det = det
+        # spans and counters of each tick (disabled unless given one); the
+        # Engine that drives this core records into it too
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.n_slots = n_slots
         self.min_bucket = min(min_bucket, n_slots)
         h, w = det.cfg.input_hw
@@ -424,7 +422,9 @@ class DetectorEngineCore:
         self._rows: list[Optional[int]] = [None] * self.cap
         self._mem = det.zero_state(self.cap)  # device-resident across ticks
         self._staged = None  # (device frames, signature): double-buffered upload
-        self.step_wall: list[float] = []  # per-tick latency (BENCH_serve)
+        # host clock per tick, from its start to the head being ready:
+        # read by bench/run.py (tick_host_ms)
+        self.step_wall: list[float] = []
 
     def _bucket_for(self, n: int) -> int:
         return min(self.n_slots, max(self.min_bucket, _pow2(max(n, 1))))
@@ -527,25 +527,42 @@ class DetectorEngineCore:
 
     # --------------------------------------------------------------- tick --
     def step(self, active: dict[int, FrameRequest]) -> list[int]:
+        """Serve one tick. With an enabled tracer it records, as children of
+        the engine's ``tick`` span: ``assemble`` and ``upload`` (only when
+        the staged upload misses, counted in ``sync_uploads``),
+        ``dispatch`` (masks, plan check and the jitted call until it
+        returns), ``stage_next`` (the next tick's upload, overlapping the
+        device), ``block``, ``copy_out`` and ``retire``."""
         if not self._row_of:  # fully drained pool: zero-cost skip
             return []
+        tr = self.tracer
         t0 = time.perf_counter()
+        occupied = self._occupied()
+        tr.count("frames", len(occupied))
         sig = self._signature()
         if self._staged is not None and self._staged[1] == sig:
             frames_dev = self._staged[0]  # pre-uploaded last tick
         else:
-            frames_dev = jnp.asarray(self._assemble(active))
+            tr.count("sync_uploads")
+            with tr.span("assemble"):
+                batch = self._assemble(active)
+            with tr.span("upload"):
+                frames_dev = jnp.asarray(batch)
+            # the asynchronous transfer keeps the host batch alive until it
+            # completes; hold no other reference to it past this point
+            del batch
         self._staged = None
-        mask = np.zeros((self.cap,), bool)
-        cold = np.zeros((self.cap,), bool)
-        for row, _ in self._occupied():
-            mask[row] = True
-        for row in self._cold:
-            cold[row] = True
-        self._cold.clear()
-        head, new_mem, dets = self.det.masked_step(
-            frames_dev, self._mem, jnp.asarray(mask), jnp.asarray(cold)
-        )
+        with tr.span("dispatch"):
+            mask = np.zeros((self.cap,), bool)
+            cold = np.zeros((self.cap,), bool)
+            for row, _ in occupied:
+                mask[row] = True
+            for row in self._cold:
+                cold[row] = True
+            self._cold.clear()
+            head, new_mem, dets = self.det.masked_step(
+                frames_dev, self._mem, jnp.asarray(mask), jnp.asarray(cold)
+            )
         # double-buffer: while the device chews on this tick, stage the
         # NEXT tick's upload. Steady state only — a finishing stream would
         # remap rows and invalidate the layout (the signature check above
@@ -553,27 +570,32 @@ class DetectorEngineCore:
         if all(
             self._cursor[s] + 1 < len(active[s].frames) for s in self._row_of
         ):
-            self._staged = (
-                jax.device_put(jnp.asarray(self._assemble(active, offset=1))),
-                self._signature(cursor_offset=1),
-            )
-        jax.block_until_ready(head)
+            with tr.span("stage_next"):
+                self._staged = (
+                    jax.device_put(jnp.asarray(self._assemble(active, offset=1))),
+                    self._signature(cursor_offset=1),
+                )
+        with tr.span("block"):
+            jax.block_until_ready(head)
         self.step_wall.append(time.perf_counter() - t0)
 
-        head_np = np.asarray(head)
-        dets_np = jax.tree_util.tree_map(np.asarray, dets)  # one transfer/field
-        self._mem = new_mem
-        finished = []
-        for row, slot in self._occupied():
-            req = active[slot]
-            req.out.append(dets_np.row(row))
-            req.heads.append(head_np[row])
-            self._cursor[slot] += 1
-            if self._cursor[slot] >= len(req.frames):
-                finished.append(slot)
-        for slot in finished:
-            self._retire(slot)
-        new_cap = self._bucket_for(len(self._row_of))
-        if new_cap < self.cap:
-            self._shrink(new_cap)
+        with tr.span("copy_out"):
+            head_np = np.asarray(head)
+            dets_np = jax.tree_util.tree_map(np.asarray, dets)  # one transfer/field
+            self._mem = new_mem
+            finished = []
+            for row, slot in occupied:
+                req = active[slot]
+                req.out.append(dets_np.row(row))
+                req.heads.append(head_np[row])
+                self._cursor[slot] += 1
+                if self._cursor[slot] >= len(req.frames):
+                    finished.append(slot)
+        with tr.span("retire"):
+            for slot in finished:
+                self._retire(slot)
+            new_cap = self._bucket_for(len(self._row_of))
+            if new_cap < self.cap:
+                self._shrink(new_cap)
+        tr.sample_memory(next(iter(head.devices())))
         return finished

@@ -27,6 +27,7 @@ one loop.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Optional, Protocol, runtime_checkable
 
@@ -36,6 +37,7 @@ import numpy as np
 
 from repro.configs.base import LMConfig
 from repro.models import zoo
+from repro.serve.trace import NULL as NULL_TRACER
 
 
 def _bucket(n: int) -> int:
@@ -309,6 +311,13 @@ class Engine:
         self.queue: list[Any] = []
         self.finished: list[Any] = []
         self.rejected: list[Any] = []  # refused/evicted requests (done=False)
+        self._queued_at: dict[int, int] = {}  # id(request) -> submit time, ns
+
+    @property
+    def tracer(self):
+        """The core's tracer (:class:`repro.serve.trace.Tracer`); a core
+        without one serves untraced."""
+        return getattr(self.core, "tracer", NULL_TRACER)
 
     def submit(self, req) -> SubmitResult:
         # reject malformed requests BEFORE they enter the queue: a bad
@@ -328,11 +337,26 @@ class Engine:
             shed = []  # shed-oldest: evict the stale front, keep the fresh
             while len(self.queue) >= pol.max_queue:
                 shed.append(self.queue.pop(0))
+                self._queued_at.pop(id(shed[-1]), None)
             self.rejected.extend(shed)
-            self.queue.append(req)
+            self._enqueue(req)
             return SubmitResult(True, reason="shed-oldest", shed=tuple(shed))
-        self.queue.append(req)
+        self._enqueue(req)
         return SubmitResult(True)
+
+    def _enqueue(self, req) -> None:
+        if self.tracer.enabled:
+            self._queued_at[id(req)] = time.time_ns()
+        self.queue.append(req)
+
+    def _admit(self, req, slot_idx: int) -> None:
+        tr = self.tracer
+        queued_at = self._queued_at.pop(id(req), None)
+        rid = getattr(req, "rid", None)
+        if queued_at is not None:
+            tr.add("queued", queued_at, time.time_ns(), rid=rid)
+        with tr.span("admit", rid=rid):
+            self.core.admit(req, slot_idx)
 
     def _active(self) -> dict[int, Any]:
         return {i: r for i, r in enumerate(self.slots) if r is not None}
@@ -343,20 +367,28 @@ class Engine:
         ``max_steps``, in which case the result's ``status`` is
         ``"truncated"`` and ``pending`` lists every undone request —
         in-flight occupants keep their slot state, so a later ``run()``
-        resumes them)."""
+        resumes them).
+
+        With an enabled tracer each iteration is one ``tick`` span (its id
+        is the ``ticks`` counter before it), holding an ``admit`` span per
+        admitted request and the core's spans; each admitted request's
+        ``queued`` span runs from ``submit`` to its admission."""
+        tr = self.tracer
         steps = 0
         while (self.queue or any(r is not None for r in self.slots)) and steps < max_steps:
-            for i in range(self.n_slots):
-                if self.slots[i] is None and self.queue:
-                    req = self.queue.pop(0)
-                    self.core.admit(req, i)
-                    self.slots[i] = req
-            active = self._active()
-            if active:
-                for i in self.core.step(active):
-                    self.slots[i].done = True
-                    self.finished.append(self.slots[i])
-                    self.slots[i] = None
+            with tr.span("tick", tick=tr.counters.get("ticks", 0)):
+                for i in range(self.n_slots):
+                    if self.slots[i] is None and self.queue:
+                        req = self.queue.pop(0)
+                        self._admit(req, i)
+                        self.slots[i] = req
+                active = self._active()
+                if active:
+                    for i in self.core.step(active):
+                        self.slots[i].done = True
+                        self.finished.append(self.slots[i])
+                        self.slots[i] = None
+            tr.count("ticks")
             steps += 1
         pending = [r for r in self.slots if r is not None] + list(self.queue)
         return EngineRunResult(

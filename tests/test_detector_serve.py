@@ -26,7 +26,7 @@ from repro.serve import (
     LMEngineCore,
     StalePlanError,
 )
-from repro.serve.detector import step_latency_ms
+from repro.serve.trace import Tracer
 
 
 @pytest.fixture(scope="module")
@@ -535,13 +535,21 @@ class TestMegabatchServing:
             AdmissionPolicy(max_queue=0)
 
     def test_step_latency_percentiles_over_synthetic_load(self, det, setup):
+        """The tracer's summary over the load: tick percentiles in order,
+        one ``tick`` and one ``block`` span per served tick, and the
+        counters of ticks and frames."""
         cfg, _, _, _ = setup
         streams = self._streams(cfg, [3] * 6)
-        eng = Engine(det, n_slots=4)
+        eng = Engine(core=DetectorEngineCore(det, n_slots=4,
+                                             tracer=Tracer(enabled=True)))
         for r, s in enumerate(streams):
             eng.submit(FrameRequest(rid=r, frames=s))
         eng.run()
-        lat = step_latency_ms(eng.core.step_wall)
-        assert set(lat) == {"step_p50_ms", "step_p95_ms", "step_p99_ms"}
-        assert 0 < lat["step_p50_ms"] <= lat["step_p95_ms"] <= lat["step_p99_ms"]
-        assert len(eng.core.step_wall) >= 3
+        summary = eng.tracer.summary()
+        tick = summary["spans"]["tick"]
+        assert set(tick) == {"n", "p50_ms", "p95_ms", "total_ms"}
+        assert 0 < tick["p50_ms"] <= tick["p95_ms"] <= tick["total_ms"]
+        assert tick["n"] == summary["counters"]["ticks"] >= 3
+        assert summary["spans"]["block"]["n"] == len(eng.core.step_wall) == tick["n"]
+        assert summary["counters"]["frames"] == 6 * 3
+        assert summary["spans"]["queued"]["n"] == summary["spans"]["admit"]["n"] == 6
