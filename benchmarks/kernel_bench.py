@@ -54,14 +54,14 @@ def run_fused() -> dict:
     assert err == 0.0, f"fused pipeline diverges from dense oracle: {err}"
 
     # the encoding layer must be ONE dispatch: 8 bit planes folded by conv
-    # linearity into a single fused pallas_call, not 8 serial sweeps
+    # linearity into a single lane-dense pallas_call, not 8 serial sweeps
     pcfg = dataclasses.replace(cfg, conv_exec="pallas")
     lp = det.plan.layers["encode"]
     spec = next(s for s in sy.layer_specs(pcfg) if s.name == "encode")
     x_t = imgs[None]  # (t_in=1, N, H, W, 3)
 
     def encode_layer(x):
-        return cplan.run_fused(
+        return cplan.run_encode(
             x, lp, pcfg,
             gamma=params["encode"]["gamma"], beta=params["encode"]["beta"],
             mean=bn["encode"]["mean"], var=bn["encode"]["var"],

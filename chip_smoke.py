@@ -153,7 +153,6 @@ def serve_phase(*, full_config: bool = True, requests: int = 6, frames: int = 3,
     """Serve ``requests`` streams of ``frames`` frames each through
     ``launch/serve.py`` on the pallas executor; count the serving step's
     kernels and compare the served outputs with the dense replay."""
-    from repro.kernels import autotune
     from repro.launch import serve
 
     argv = ["--arch", "snn-det", "--conv-exec", "pallas", "--requests",
@@ -170,7 +169,7 @@ def serve_phase(*, full_config: bool = True, requests: int = 6, frames: int = 3,
         "serve_s": serve_s,
         "first_tick_s": core.step_wall[0],
         "tracer": eng.tracer.summary(),
-        "fused_layers": len(autotune.detector_layer_shapes(det.cfg)),
+        "fused_layers": sum(1 for n in det.plan.layers if "gamma" in det.params[n]),
         "tpu_custom_calls": count_kernels(det, core.cap),
     }
     _check(summary["requests_done"] == requests,

@@ -391,42 +391,33 @@ def run_fused(
     out_t: int,
     affine: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The whole per-layer pipeline — conv → FXP rescale → tdBN inference
-    affine → LIF over ``out_t`` steps — in ONE fused Pallas dispatch
-    (kernels/fused_pipeline.py), membrane resident in VMEM across T.
+    """The whole per-layer pipeline of a binary spike layer — conv → FXP
+    rescale → tdBN inference affine → LIF over ``out_t`` steps — in ONE
+    fused Pallas dispatch (kernels/fused_pipeline.py), membrane resident in
+    VMEM across T.
 
     Returns (spikes (out_t, N, H, W, C) f32 {0,1}, final membrane
     (N, H, W, C) f32) — drop-in for the unfused conv → ``tdbn_apply``
     (training=False) → ``lif_over_time`` chain, BIT-IDENTICAL to it (same
     float ops in the same order; integer conv accumulation is
-    order-independent).
-
-    The 8-bit encoding layer folds its bit-serial planes into the u8 pixel
-    values (Σ_b 2^b·conv(plane_b) = conv(u8), exact in f32), so encode is
-    one dispatch too. Dispatch tiling comes from ``lp.tile`` (autotuned).
+    order-independent). Dispatch tiling comes from ``lp.tile``
+    (autotuned). The 8-bit encoding layer runs through :func:`run_encode`.
 
     ``affine``: optional precomputed parameter bundle (see
     :func:`precompute_affines`) — compile-once callers hoist the per-layer
     bundle build out of the frame loop; when None it is built inline from
     the gamma/beta/mean/var arguments (identical values either way)."""
+    assert lp.in_bits == 1, "the encoding layer runs through run_encode"
     bh, bw = cfg.block_hw
     interpret = getattr(cfg, "kernel_interpret", None)
-    if lp.in_bits == 8:
-        # u8-grid values = the exact fold of the 8 bit-serial planes
-        x = _quantize_input_u8(x_t).astype(jnp.float32)
-        scale_eff = lp.scale / 255.0
-    else:
-        x = x_t
-        scale_eff = lp.scale
     if affine is None:
-        affine = kops.affine_bundle(lp.packed, scale_eff, mean, var, gamma, beta)
+        affine = kops.affine_bundle(lp.packed, lp.scale, mean, var, gamma, beta)
     return kops.fused_conv_bn_lif(
-        x,
+        x_t,
         lp.packed,
         affine,
         v0=v0,
         out_t=out_t,
-        in_bits=lp.in_bits,
         bn_scale=1.0 * cfg.threshold,  # tdbn_apply's alpha(=1)·threshold
         threshold=cfg.threshold,
         leak=cfg.leak,
@@ -438,6 +429,52 @@ def run_fused(
         mrows=lp.tile.mrows,
         mcols=lp.tile.mcols,
         interpret=interpret,
+    )
+
+
+def run_encode(
+    x_t: jax.Array,
+    lp: CompressedLayerPlan,
+    cfg,
+    *,
+    gamma: jax.Array,
+    beta: jax.Array,
+    mean: jax.Array,
+    var: jax.Array,
+    v0: jax.Array | None,
+    out_t: int,
+    affine: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The 8-bit encoding layer (``x_t``: (1, N, H, W, 3) frames in [0, 1])
+    in ONE lane-dense Pallas dispatch (kernels/encode_pipeline.py): u8
+    quantisation (:func:`_quantize_input_u8`'s f32 ops, in the kernel), the
+    conv over the u8 values — the exact fold of the 8 bit-serial planes —
+    FXP rescale, tdBN affine and LIF over ``out_t`` steps from one drive.
+
+    Returns lane-dense (spikes (out_t, N, H, W·C) int8 {0,1}, membrane
+    (N, H, W·C) f32): NHWC element order, bit-identical to the unfused
+    chain's on finite frames (``kops.lane_dense_nhwc`` gives the NHWC
+    shape). ``v0`` may be either shape."""
+    assert lp.in_bits == 8 and x_t.shape[0] == 1, (lp.in_bits, x_t.shape)
+    bh, bw = cfg.block_hw
+    if affine is None:
+        affine = kops.affine_bundle(
+            lp.packed, lp.scale / 255.0, mean, var, gamma, beta
+        )
+    return kops.encode_conv_bn_lif(
+        x_t[0],
+        lp.w_q,
+        affine,
+        v0=v0,
+        out_t=out_t,
+        bn_scale=1.0 * cfg.threshold,  # tdbn_apply's alpha(=1)·threshold
+        threshold=cfg.threshold,
+        leak=cfg.leak,
+        reset=getattr(cfg, "reset", "hard"),
+        v_init=getattr(cfg, "v_init", 0.0),
+        bh=bh,
+        bw=bw,
+        interpret=getattr(cfg, "kernel_interpret", None),
     )
 
 

@@ -85,7 +85,7 @@ class LayerShape(NamedTuple):
     kw: int
     cin: int  # true (unpadded) input channels
     kout: int  # true output channels
-    in_bits: int  # 1 = binary spikes, 8 = u8 encode input
+    in_bits: int  # 1 = binary spikes (the only input the tuned kernel takes)
     t_in: int
     t_out: int
     h: int  # feature-map resolution the layer runs at
@@ -251,12 +251,11 @@ def candidates(shape: LayerShape) -> list[TileConfig]:
     out = []
     cin_p = -(-shape.cin // 8) * 8
     ph, pw = shape.bh + shape.kh - 1, shape.bw + shape.kw - 1
-    in_bytes = 4 if shape.in_bits == 8 else 1
     for kblk in kblks:
         for mr, mc in _macro_shapes(nbh, nbw):
             bpg = mr * mc
             vmem = (
-                shape.t_in * bpg * ph * pw * cin_p * in_bytes  # spike tile
+                shape.t_in * bpg * ph * pw * cin_p  # int8 spike tile
                 + shape.t_in * bpg * ph * pw * cin_p * 4  # its f32 widening
                 + shape.kh * shape.kw * cin_p * kblk * 2  # maskp+decoded w
                 + bpg * shape.bh * shape.bw * kblk * (4 + 4 + shape.t_out)
@@ -276,13 +275,8 @@ def _synthetic_layer(shape: LayerShape, rng: np.random.Generator):
     density = 0.2 if shape.kh > 1 else 0.6
     w[rng.random(w.shape) > density] = 0
     w = w.astype(np.int8)
-    if shape.in_bits == 8:
-        x = rng.integers(0, 256, (shape.t_in, 1, shape.h, shape.w, shape.cin))
-        x_t = jnp.asarray(x, jnp.float32)
-    else:
-        x = rng.random((shape.t_in, 1, shape.h, shape.w, shape.cin)) < 0.25
-        x_t = jnp.asarray(x, jnp.float32)
-    return w, x_t
+    x = rng.random((shape.t_in, 1, shape.h, shape.w, shape.cin)) < 0.25
+    return w, jnp.asarray(x, jnp.float32)
 
 
 def tune_layer(
@@ -326,7 +320,6 @@ def tune_layer(
                 affine,
                 v0=None,
                 out_t=shape.t_out,
-                in_bits=shape.in_bits,
                 bn_scale=threshold,
                 threshold=threshold,
                 leak=leak,
@@ -360,14 +353,16 @@ def tune_layer(
 
 
 def detector_layer_shapes(cfg) -> dict[str, LayerShape]:
-    """Every fused-eligible conv layer of an ``SNNDetConfig`` as
-    :class:`LayerShape` s (the head has no tdBN/LIF and is not fused)."""
+    """Every conv layer of an ``SNNDetConfig`` that the tiled fused kernel
+    runs, as :class:`LayerShape` s: the head has no tdBN/LIF and is not
+    fused, and the 8-bit encoding layer has its own untiled kernel
+    (kernels/encode_pipeline.py)."""
     from repro.models import snn_yolo as sy  # lazy: avoid import cycle
 
     bh, bw = cfg.block_hw
     out = {}
     for spec in sy.layer_specs(cfg):
-        if spec.name == "head":
+        if spec.name == "head" or spec.bits_in != 1:
             continue
         out[spec.name] = LayerShape(
             kh=spec.k,

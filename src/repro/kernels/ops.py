@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import encode_pipeline as ep
 from . import fused_pipeline as fp
 from . import gated_one_to_all as g2a
 from . import spike_lif as sl
@@ -338,7 +339,6 @@ def affine_bundle(
         "mr",
         "mc",
         "t_out",
-        "in_bits",
         "tap_alive",
         "bn_scale",
         "threshold",
@@ -368,7 +368,6 @@ def _dispatch_fused(
     mr,
     mc,
     t_out,
-    in_bits,
     tap_alive,
     bn_scale,
     threshold,
@@ -394,7 +393,6 @@ def _dispatch_fused(
         nbt=nbt,
         bpg=mr * mc,
         t_out=t_out,
-        in_bits=in_bits,
         tap_alive=tap_alive,
         bn_scale=bn_scale,
         threshold=threshold,
@@ -430,13 +428,12 @@ def _normalize_tiling(
 
 
 def fused_conv_bn_lif(
-    x_t: jax.Array,  # (t_in, N, H, W, C): int8 {0,1} spikes, or u8-valued f32
+    x_t: jax.Array,  # (t_in, N, H, W, C) {0,1} spikes
     pw: PackedConvWeights,
     affine: jax.Array,  # (KB, 5, KBLK) from affine_bundle
     *,
     v0: jax.Array | None,  # (N, H, W, Kout) f32 initial membrane, None=cold
     out_t: int,
-    in_bits: int,
     bn_scale: float,
     threshold: float,
     leak: float,
@@ -454,9 +451,8 @@ def fused_conv_bn_lif(
     over ``out_t`` steps) in ONE Pallas dispatch. Returns
     (spikes (out_t, N, H, W, Kout) f32 {0,1}, final membrane (N, H, W, Kout) f32).
 
-    ``in_bits=8`` runs the encoding layer: ``x_t`` then carries the u8-grid
-    pixel VALUES (as f32) — the exact fold of the 8 bit-serial planes, so
-    encode is one dispatch of the same kernel (see fused_pipeline.py).
+    Binary spike layers only: the 8-bit encoding layer has its own lane-
+    dense kernel (:func:`encode_conv_bn_lif`).
 
     ``mrows``/``mcols`` select the MACRO-TILE: each grid step processes an
     mrows×mcols group of spatial blocks (whole block-rows, or r×c groups),
@@ -487,9 +483,8 @@ def fused_conv_bn_lif(
     t_in, n, h, w, _ = x_t.shape
     nbt, mrows, mcols = _normalize_tiling(nbt, mrows, mcols, h // bh, w // bw)
     pad = (pw.kh - 1) // 2
-    in_dtype = jnp.float32 if in_bits == 8 else jnp.int8
     flat = _block_layout(
-        x_t.reshape((t_in * n,) + x_t.shape[2:]).astype(in_dtype),
+        x_t.reshape((t_in * n,) + x_t.shape[2:]).astype(jnp.int8),
         bh=bh,
         bw=bw,
         pad=pad,
@@ -524,7 +519,6 @@ def fused_conv_bn_lif(
         mr=mrows,
         mc=mcols,
         t_out=out_t,
-        in_bits=in_bits,
         tap_alive=tuple(pw.tap_alive),
         bn_scale=bn_scale,
         threshold=threshold,
@@ -535,6 +529,86 @@ def fused_conv_bn_lif(
         batch=n,
         kout=pw.kout,
         interpret=interpret,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Encoding layer: RGB frames → spikes in one lane-dense dispatch
+# ---------------------------------------------------------------------------
+#
+# The lane-dense layout: an NHWC array (…, N, H, W, C) viewed as
+# (…, N, H, W·C) — the same elements in the same order, a frame row's W·C
+# values side by side in the minor dimension.
+
+
+def lane_dense_nhwc(x: jax.Array, c: int) -> jax.Array:
+    """(…, H, W·C) → (…, H, W, C): the NHWC shape of a lane-dense array (a
+    reshape; on a TPU a relayout copy, so the serving step keeps the
+    lane-dense shape)."""
+    return x.reshape(x.shape[:-1] + (-1, c))
+
+
+def lane_dense_maxpool(spk: jax.Array, c: int) -> jax.Array:
+    """2×2 max-pool (the spike OR gate) of lane-dense spikes (…, H, W·C)
+    with H and W even → NHWC (…, H/2, W/2, C). Row pairs split the row
+    axis; column pairs are neighbouring C-lane groups."""
+    *lead, h, lanes = spk.shape
+    y = spk.reshape(*lead, h // 2, 2, lanes).max(axis=-2)
+    return y.reshape(*lead, h // 2, lanes // (2 * c), 2, c).max(axis=-2)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("bh", "bw", "t_out", "bn_scale", "threshold", "leak",
+                     "reset", "v_init", "interpret"),
+)
+def _dispatch_encode(frames, bands, affine, v0, *, bh, bw, t_out, bn_scale,
+                     threshold, leak, reset, v_init, interpret):
+    n, h, w, _ = frames.shape
+    if v0 is not None:
+        v0 = v0.astype(jnp.float32).reshape(n * h, -1)
+    spk, mem = ep.encode_pallas(
+        # the planar view: a TPU keeps the frames W-minor, so this
+        # transpose reads them as they lie
+        frames.astype(jnp.float32).transpose(0, 3, 1, 2), bands, affine, v0,
+        bh=bh, bw=bw, t_out=t_out, bn_scale=bn_scale, threshold=threshold,
+        leak=leak, reset=reset, v_init=v_init, interpret=interpret,
+    )
+    return spk.reshape(t_out, n, h, -1), mem.reshape(n, h, -1)
+
+
+def encode_conv_bn_lif(
+    frames: jax.Array,  # (N, H, W, Cin) f32 in [0, 1]
+    w_q: jax.Array,  # (3, 3, Cin, C) int8 quantized weights
+    affine: jax.Array,  # (KB, 5, KBLK) from affine_bundle
+    *,
+    v0: jax.Array | None,  # (N, H, W·C) or (N, H, W, C); None = cold
+    out_t: int,
+    bn_scale: float,
+    threshold: float,
+    leak: float,
+    reset: str = "hard",
+    v_init: float = 0.0,
+    bh: int = g2a.BLOCK_H,
+    bw: int = g2a.BLOCK_W,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The 8-bit encoding layer (u8 quantisation → block conv → FXP
+    rescale → tdBN affine → LIF over ``out_t`` steps from one drive) in ONE
+    lane-dense Pallas dispatch (kernels/encode_pipeline.py). Returns lane-
+    dense (spikes (out_t, N, H, W·C) int8 {0,1}, final membrane (N, H, W·C)
+    f32), bit-identical in NHWC element order to the unfused chain's on
+    finite frames. The band matrices are built from the static weights at
+    trace time, as the blocked path predecodes its weights."""
+    cin, kout = w_q.shape[-2:]
+    wt = ep.input_tile(frames.shape[2], bw)
+    bands = ep.tile_bands(ep.band_matrices(np.asarray(w_q), bw), cin, wt)
+    return _dispatch_encode(
+        frames, jnp.asarray(bands, jnp.bfloat16),
+        ep.affine_lanes(affine, kout, bw), v0,
+        bh=bh, bw=bw, t_out=out_t, bn_scale=bn_scale, threshold=threshold,
+        leak=leak, reset=reset, v_init=v_init,
+        interpret=auto_interpret(interpret),
     )
 
 

@@ -46,6 +46,7 @@ from repro.core import lif as lifm
 from repro.core import plan as cplan
 from repro.core import pruning, quant
 from repro.core import spike_conv as sc
+from repro.kernels import ops as kops
 
 Mode = Literal["snn", "ann", "qnn", "bnn"]
 ConvExec = Literal["dense", "gated", "pallas"]
@@ -304,6 +305,23 @@ def _conv_bn_act(x_t, layer_p, layer_s, cfg, train, *, name, **kw):
         return _conv_bn_act_body(x_t, layer_p, layer_s, cfg, train, name=name, **kw)
 
 
+def _fusable(x_t, t_out, layer_p, cfg, train, *, name, plan, taps) -> bool:
+    """Whether a layer can run as one fused Pallas dispatch: eval mode, a
+    spiking net on the pallas executor with the layer in the plan, a tdBN
+    to fold, and an input of 1 or ``t_out`` steps. Recording ``taps``
+    (the tdBN drive) keeps the chain unfused."""
+    return (
+        not train
+        and taps is None
+        and cfg.mode == "snn"
+        and cfg.conv_exec == "pallas"
+        and plan is not None
+        and name in plan.layers
+        and "gamma" in layer_p
+        and x_t.shape[0] in (1, t_out)
+    )
+
+
 def _conv_bn_act_body(
     x_t, layer_p, layer_s, cfg, train, *, out_t=None, name=None, plan=None, v0=None,
     affine=None, taps=None, pool=False,
@@ -327,19 +345,15 @@ def _conv_bn_act_body(
     with the unfused path, so this is purely a dataflow change. When
     ``taps`` is given the chain stays unfused so the tdBN output can be
     recorded — numerics are identical either way (PR 6 conformance).
+    The 8-bit encoding layer's fused form is :func:`_encode_pool`'s.
     """
     t_out = out_t or x_t.shape[0]
     pool_inside = pool and cfg.pool_drive and cfg.mode == "snn"
     if (
-        not train
-        and taps is None
-        and not pool_inside
-        and cfg.mode == "snn"
-        and cfg.conv_exec == "pallas"
-        and plan is not None
-        and name in plan.layers
-        and "gamma" in layer_p
-        and (x_t.shape[0] in (1, t_out))
+        not pool_inside
+        and _fusable(x_t, t_out, layer_p, cfg, train, name=name, plan=plan,
+                     taps=taps)
+        and plan.layers[name].in_bits == 1
     ):
         act, v_final = cplan.run_fused(
             x_t,
@@ -365,6 +379,53 @@ def _conv_bn_act_body(
         y_t = _maxpool_t(y_t)
     act, v_final = _activation(y_t, cfg, v0=v0)
     return act, new_s, v_final
+
+
+def _encode_pool(x_t, layer_p, layer_s, cfg, train, *, out_t, plan, v0, affine,
+                 taps):
+    """The 8-bit encoding layer and the 2×2 pool after it (``pool0``).
+    Returns (pooled spikes, spikes, new bn state, membrane).
+
+    On the pallas executor at eval time the layer is one lane-dense
+    dispatch (``plan.run_encode``): its spikes and membrane keep the
+    lane-dense shape (N, H, W·C) of NHWC element order, the pool
+    reads the int8 spikes in that shape, and the membrane leaf a session
+    carries stays in it from frame to frame. The returned spikes have the
+    NHWC shape. Everywhere else the layer is :func:`_conv_bn_act`, then
+    :func:`_pool_t` (with ``cfg.pool_drive`` the pool ran inside)."""
+    t_out = out_t or 1
+    pool_drive = cfg.pool_drive and cfg.mode == "snn"
+    bh, bw = cfg.block_hw
+    _, _, h, w, _ = x_t.shape
+    if (
+        not pool_drive
+        and _fusable(x_t, t_out, layer_p, cfg, train, name="encode",
+                     plan=plan, taps=taps)
+        and h % bh == 0 and w % bw == 0  # whole blocks, as block conv needs
+    ):
+        c = layer_p["w"].shape[-1]
+        with jax.named_scope("encode"):
+            spk, v = cplan.run_encode(
+                x_t, plan.layers["encode"], cfg, gamma=layer_p["gamma"],
+                beta=layer_p["beta"], mean=layer_s["mean"],
+                var=layer_s["var"], v0=v0, out_t=t_out, affine=affine,
+            )
+            s_t = kops.lane_dense_nhwc(spk, c).astype(jnp.float32)
+        with jax.named_scope("pool0"):
+            if cfg.pool_mode == "or" and h % 2 == 0 and w % 2 == 0:
+                pooled = kops.lane_dense_maxpool(spk, c).astype(jnp.float32)
+            else:
+                pooled = _pool_t(s_t, cfg)
+        return pooled, s_t, layer_s, v  # eval-mode tdBN state is unchanged
+    s_t, new_s, v = _conv_bn_act(
+        x_t, layer_p, layer_s, cfg, train, out_t=out_t, name="encode",
+        plan=plan, v0=v0, affine=affine, taps=taps, pool=True,
+    )
+    pooled = s_t
+    if not pool_drive:
+        with jax.named_scope("pool0"):
+            pooled = _pool_t(s_t, cfg)
+    return pooled, s_t, new_s, v
 
 
 def _maxpool_t(x_t):
@@ -483,17 +544,14 @@ def forward(
     # --- encode (ANN layer: fires once — or rate-codes when rate_encode) ---
     enc_t = full_t if (cfg.rate_encode and cfg.mode == "snn") else None
     pd = cfg.pool_drive and cfg.mode == "snn"  # pools already ran inside
-    s_t, new_state["encode"], new_mem["encode"] = _conv_bn_act(
-        x_t, params["encode"], bn_state["encode"], cfg, train, out_t=enc_t,
-        name="encode", plan=plan, v0=mem.get("encode"),
-        affine=aff.get("encode"), taps=taps, pool=True,
+    s_t, aux["spikes"]["encode"], new_state["encode"], new_mem["encode"] = (
+        _encode_pool(
+            x_t, params["encode"], bn_state["encode"], cfg, train, out_t=enc_t,
+            plan=plan, v0=mem.get("encode"), affine=aff.get("encode"),
+            taps=taps,
+        )
     )
-    aux["spikes"]["encode"] = s_t
-    n_pool = 0
-    if not pd:
-        with jax.named_scope(f"pool{n_pool}"):
-            s_t = _pool_t(s_t, cfg)
-        n_pool += 1
+    n_pool = 0 if pd else 1
 
     # --- conv block: in_T=1, out_T=full_t (mixed time steps) ---
     out_t = full_t if cfg.mixed_time else s_t.shape[0]

@@ -77,7 +77,11 @@ def run_executor(executor: str, params, bn, frames, cfg=None) -> dict:
         out[f"stream_head_{k}"] = np.asarray(step.head)
         out[f"stream_valid_{k}"] = np.asarray(step.detections.valid)
     for name, v in sess.state.items():
-        out[f"mem/{name}"] = np.asarray(v)
+        # compared in NHWC element order: the pallas executor's encode leaf
+        # is lane-dense, (N, H, W/bw, bw·C)
+        v = np.asarray(v)
+        c = params[name]["w"].shape[-1]
+        out[f"mem/{name}"] = v.reshape(v.shape[:2] + (-1, c))
     return out
 
 

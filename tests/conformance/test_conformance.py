@@ -180,8 +180,8 @@ class TestFusedPathActive:
     """The pallas rows above must test the FUSED pipeline, not a silent
     fallback: the compiled step's trace must contain exactly one
     pallas_call per fused-eligible (conv+tdBN+LIF) layer — encode's 8 bit-
-    serial planes fold into its single dispatch, and the pointwise head
-    (no tdBN/LIF to fuse) contracts outside the kernel."""
+    serial planes fold into its single lane-dense dispatch, and the
+    pointwise head (no tdBN/LIF to fuse) contracts outside the kernel."""
 
     def test_one_dispatch_per_fused_layer(self, inputs):
         import dataclasses

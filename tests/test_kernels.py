@@ -98,7 +98,7 @@ class TestFusedPipelineKernel:
     time) vs in-kernel decode, which must be indistinguishable."""
 
     def _setup(self, *, kh, cin, kout, t_in, t_out, h=12, w=16, bh=6, bw=8,
-               in_bits=1, seed=0):
+               seed=0):
         from repro.core import block_conv as bc
         from repro.core import lif as lifm
 
@@ -111,14 +111,7 @@ class TestFusedPipelineKernel:
         gamma = jnp.asarray(rng.normal(size=kout), jnp.float32)
         beta = jnp.asarray(rng.normal(size=kout), jnp.float32)
         affine = ops.affine_bundle(pw, scale, mean, var, gamma, beta)
-        if in_bits == 8:
-            x_t = jnp.asarray(
-                rng.integers(0, 256, (t_in, 2, h, w, cin)), jnp.float32
-            )
-        else:
-            x_t = jnp.asarray(
-                rng.integers(0, 2, (t_in, 2, h, w, cin)), jnp.float32
-            )
+        x_t = jnp.asarray(rng.integers(0, 2, (t_in, 2, h, w, cin)), jnp.float32)
         thr, leak = 0.5, 0.25
 
         def unfused(x_t):
@@ -152,8 +145,8 @@ class TestFusedPipelineKernel:
 
         def fused(x_t, predecode):
             return ops.fused_conv_bn_lif(
-                x_t, pw, affine, v0=None, out_t=t_out, in_bits=in_bits,
-                bn_scale=thr, threshold=thr, leak=leak, bh=bh, bw=bw,
+                x_t, pw, affine, v0=None, out_t=t_out, bn_scale=thr,
+                threshold=thr, leak=leak, bh=bh, bw=bw,
                 nbt=2, predecode=predecode,
             )
 
@@ -181,16 +174,14 @@ class TestFusedPipelineKernel:
         )
 
     @pytest.mark.parametrize(
-        "kh,cin,kout,t_in,t_out,in_bits",
-        [(3, 8, 16, 2, 2, 1), (1, 16, 8, 3, 3, 1), (3, 3, 8, 1, 3, 8)],
+        "kh,cin,kout,t_in,t_out",
+        [(3, 8, 16, 2, 2), (1, 16, 8, 3, 3), (3, 3, 8, 1, 3)],
     )
-    def test_predecode_equals_in_kernel_decode(
-        self, kh, cin, kout, t_in, t_out, in_bits
-    ):
+    def test_predecode_equals_in_kernel_decode(self, kh, cin, kout, t_in, t_out):
         """The docstring promise: decoder-in-kernel (streaming weights) and
         predecoded (static weights, decode at trace time) are bit-identical."""
         x_t, _, fused = self._setup(
-            kh=kh, cin=cin, kout=kout, t_in=t_in, t_out=t_out, in_bits=in_bits
+            kh=kh, cin=cin, kout=kout, t_in=t_in, t_out=t_out
         )
         spk_p, mem_p = fused(x_t, predecode=True)
         spk_k, mem_k = fused(x_t, predecode=False)
@@ -198,32 +189,46 @@ class TestFusedPipelineKernel:
         np.testing.assert_array_equal(np.asarray(mem_p), np.asarray(mem_k))
 
     def test_encode_in_bits8_matches_bitserial_reference(self):
-        """u8 values folded into one dispatch ≡ the literal 8-plane
-        bit-serial accumulation (conv linearity over exact integers)."""
+        """The lane-dense encode kernel over u8 values ≡ the literal 8-plane
+        bit-serial accumulation (conv linearity over exact integers), run
+        through the eager unfused affine/LIF chain: spikes bit-exact,
+        membranes within the eager chain's few ulp (see ``unfused``)."""
         from repro.core import bitserial, block_conv as bc
+        from repro.core import lif as lifm
 
-        x_t, unfused, fused = self._setup(
-            kh=3, cin=3, kout=8, t_in=1, t_out=2, in_bits=8, seed=3
-        )
-        spk_g, _ = fused(x_t, predecode=True)
-        # plane-serial reference conv, then the same affine/LIF chain via
-        # the unfused oracle path run on conv outputs is overkill here —
-        # instead assert the fold at the conv level feeding the kernel:
-        x_u8 = np.asarray(x_t[0], np.uint8)
-        planes = bitserial.to_bitplanes(jnp.asarray(x_u8))
-        acc = sum(
-            (2**b)
-            * np.asarray(
-                bc.block_conv2d(planes[b], jnp.zeros((3, 3, 3, 8)) + 1.0,
-                                block_h=6, block_w=8)
-            )
-            for b in range(8)
-        )
-        whole = np.asarray(
-            bc.block_conv2d(x_t[0], jnp.zeros((3, 3, 3, 8)) + 1.0,
-                            block_h=6, block_w=8)
-        )
-        np.testing.assert_array_equal(acc, whole)
+        rng = np.random.default_rng(3)
+        w_int = _sparse_int8_weights(4, 3, 3, 3, 8, 0.5)
+        pw = ops.pack_conv_weights(w_int, kblk=8)
+        scale = jnp.float32(1.0 / 128 / 255)
+        mean, var, gamma, beta = (
+            jnp.asarray(v, jnp.float32) for v in (
+                rng.normal(size=8) * 8, rng.random(8) * 64 + 1,
+                rng.normal(size=8), rng.normal(size=8)))
+        affine = ops.affine_bundle(pw, scale, mean, var, gamma, beta)
+        x_u8 = jnp.asarray(rng.integers(0, 256, (2, 12, 16, 3)), jnp.uint8)
+        spk, mem = ops.encode_conv_bn_lif(
+            x_u8 / jnp.float32(255), jnp.asarray(w_int), affine, v0=None,
+            out_t=2, bn_scale=0.5,
+            threshold=0.5, leak=0.25, bh=6, bw=8)
+
+        planes = bitserial.to_bitplanes(x_u8).astype(jnp.float32)
+        acc = sum((2**b) * bc.block_conv2d(
+            planes[b], jnp.asarray(w_int, jnp.float32), block_h=6, block_w=8)
+            for b in range(8))
+        p = lifm.TdBNParams(gamma=gamma, beta=beta)
+        st = lifm.TdBNState(mean=mean, var=var, count=jnp.zeros((), jnp.int32))
+        y, _ = lifm.tdbn_apply(p, st, (acc * scale)[None], threshold=0.5,
+                               training=False)
+        v = jnp.zeros(y.shape[1:], jnp.float32)
+        for t in range(2):
+            v = v * 0.25 + y[0]
+            s_t = v >= 0.5
+            np.testing.assert_array_equal(
+                np.asarray(ops.lane_dense_nhwc(spk[t], 8)), np.asarray(s_t))
+            v = jnp.where(s_t, 0.0, v)
+        assert 0 < float(jnp.mean(spk)) < 1  # neither silent nor saturated
+        np.testing.assert_allclose(np.asarray(ops.lane_dense_nhwc(mem, 8)),
+                                   np.asarray(v), atol=1e-6, rtol=0)
 
 
 class TestMacroTileFusedPipeline:
@@ -247,8 +252,8 @@ class TestMacroTileFusedPipeline:
         )
         x_t = jnp.asarray(rng.integers(0, 2, (t_in, 2, h, w, cin)), jnp.float32)
         return ops.fused_conv_bn_lif(
-            x_t, pw, affine, v0=v0, out_t=t_out, in_bits=1,
-            bn_scale=0.5, threshold=0.5, leak=0.25, reset=reset,
+            x_t, pw, affine, v0=v0, out_t=t_out, bn_scale=0.5, threshold=0.5,
+            leak=0.25, reset=reset,
             bh=bh, bw=bw, nbt=nbt if nbt is not None else mrows * mcols,
             mrows=mrows, mcols=mcols,
         )
@@ -347,20 +352,21 @@ class TestCompiledDecoderRefused:
                 affine = ops.affine_bundle(
                     pw, jnp.float32(1.0), *(jnp.ones(8, jnp.float32),) * 4)
                 ops.fused_conv_bn_lif(
-                    spikes[None], pw, affine, v0=None, out_t=1, in_bits=1,
-                    bn_scale=0.5, threshold=0.5, leak=0.25, bh=6, bw=8,
-                    predecode=False, interpret=False)
+                    spikes[None], pw, affine, v0=None, out_t=1, bn_scale=0.5,
+                    threshold=0.5, leak=0.25, bh=6, bw=8, predecode=False,
+                    interpret=False)
             else:
                 packed = pack_weights(np.eye(64, dtype=np.float32), kblk=64, nblk=64)
                 ops.bitmask_matmul(jnp.ones((8, 64)), packed, interpret=False)
 
 
 class TestFusedKernelVsDenseOracle:
-    """The fused kernel's one body (tap-shifted windows, one MXU dot per
-    live tap) in interpret mode, against the dense executor's unfused
-    conv → tdBN → LIF layer — both through ``snn_yolo._conv_bn_act``, both
-    jitted, as the detector's forward runs them. Spikes and membranes must
-    be bit-identical for every layer kind the detector has."""
+    """The fused kernels in interpret mode — the blocked spike-layer body
+    (tap-shifted windows, one MXU dot per live tap) and the lane-dense
+    encode — against the dense executor's unfused conv → tdBN → LIF layer,
+    both jitted through ``snn_yolo``'s layer functions, as the detector's
+    forward runs them. Spikes and membranes (in NHWC element order) must be
+    bit-identical for every layer kind the detector has."""
 
     @pytest.mark.parametrize(
         "kh,cin,kout,t_in,t_out,in_bits,tile",
@@ -368,7 +374,7 @@ class TestFusedKernelVsDenseOracle:
             (3, 8, 16, 3, 3, 1, (16, 1, 1, 1)),  # 3×3, one block per step
             (3, 16, 24, 1, 3, 1, (8, 2, 2, 2)),  # 3×3, T 1→3, 2×2 macro, 3 K-blocks
             (1, 24, 16, 3, 3, 1, (16, 4, 2, 2)),  # 1×1, macro-tile, one dot
-            (3, 3, 16, 1, 1, 8, (16, 1, 1, 4)),  # encode: u8 input, 1×4 macro
+            (3, 3, 16, 1, 1, 8, (16, 1, 1, 4)),  # encode: u8 input (no tiling)
             (3, 3, 8, 1, 3, 8, (8, 2, 2, 2)),  # rate-coded encode, T 1→3
         ],
     )
@@ -402,13 +408,123 @@ class TestFusedKernelVsDenseOracle:
         x_t = jnp.asarray(x_t, jnp.float32)
 
         def layer(c):
+            if in_bits == 8:  # encode and its pool, as the forward runs them
+                return jax.jit(lambda x: sy._encode_pool(
+                    x, layer_p, layer_s, c, False, out_t=t_out, plan=plan,
+                    v0=None, affine=None, taps=None)[1:])
             return jax.jit(lambda x: sy._conv_bn_act(
                 x, layer_p, layer_s, c, False, out_t=t_out, name=name, plan=plan
             ))
 
         spk_p, _, mem_p = layer(cfg)(x_t)
         spk_d, _, mem_d = layer(dataclasses.replace(cfg, conv_exec="dense"))(x_t)
+        if in_bits == 8:
+            mem_p = ops.lane_dense_nhwc(mem_p, kout)
         assert spk_p.shape == (t_out, 2, 24, 32, kout)
         assert 0 < float(spk_d.mean()) < 1  # spikes neither silent nor saturated
         np.testing.assert_array_equal(np.asarray(spk_p), np.asarray(spk_d))
         np.testing.assert_array_equal(np.asarray(mem_p), np.asarray(mem_d))
+
+
+class TestLaneDenseEncode:
+    """The lane-dense encode kernel (interpret mode) against the dense
+    executor's unfused encode layer, both jitted through
+    ``snn_yolo._encode_pool``: spikes, membranes (NHWC element order) and
+    pooled spikes bit-identical over 3 frames with the membrane carried,
+    frames at 0 and 255, and ±127 weights on every tap of two channels so
+    the folded edge entries of the band matrices reach ±254."""
+
+    @staticmethod
+    def _encode(rng, *, hw, block, kout=16, reset, v_init, out_t):
+        import dataclasses
+
+        from repro.core import plan as cplan
+        from repro.kernels import encode_pipeline as ep
+        from repro.models import snn_yolo as sy
+
+        cfg = sy.SNNDetConfig(input_hw=hw, block_hw=block, use_block_conv=True,
+                              conv_exec="pallas", reset=reset, v_init=v_init)
+        w = rng.normal(size=(3, 3, 3, kout)).astype(np.float32)
+        w[rng.random(w.shape) < 0.6] = 0.0
+        top = np.abs(w).max()
+        w[..., 0], w[..., 1] = top, -top  # quantize to +127 / -127
+        lp = cplan.build_layer_plan("encode", jnp.asarray(w), in_bits=8)
+        bands = ep.band_matrices(np.asarray(lp.w_q), block[1])
+        assert np.abs(bands).max() == 254
+        plan = cplan.DetectorPlan(layers={"encode": lp}, block_hw=block)
+        layer_p = {"w": jnp.asarray(w),
+                   "gamma": jnp.asarray(rng.normal(size=kout), jnp.float32),
+                   "beta": jnp.asarray(rng.normal(size=kout), jnp.float32)}
+        layer_s = {"mean": jnp.asarray(rng.normal(size=kout) * 600, jnp.float32),
+                   "var": jnp.asarray(rng.random(kout) * 4e5 + 1, jnp.float32),
+                   "count": jnp.zeros((), jnp.int32)}
+
+        def layer(c):
+            return jax.jit(lambda x, v0: sy._encode_pool(
+                x, layer_p, layer_s, c, False, out_t=out_t, plan=plan, v0=v0,
+                affine=None, taps=None))
+
+        return layer(cfg), layer(dataclasses.replace(cfg, conv_exec="dense"))
+
+    @pytest.mark.parametrize("out_t,v_init", [(1, 0.0), (3, 0.25)])
+    @pytest.mark.parametrize("reset", ["hard", "soft"])
+    @pytest.mark.parametrize("hw,block", [((24, 32), (6, 8)),
+                                          ((36, 64), (18, 32))])
+    def test_bit_identical_to_dense_over_frames(self, hw, block, reset,
+                                                out_t, v_init):
+        rng = np.random.default_rng(hw[1] + out_t)
+        pallas, dense = self._encode(rng, hw=hw, block=block, reset=reset,
+                                     v_init=v_init, out_t=out_t)
+        frames = rng.integers(0, 256, (3, 3) + hw + (3,)) / 255.0
+        frames[:, 0], frames[:, 1] = 0.0, 1.0  # u8 values 0 and 255
+        v_p = v_d = None
+        for k in range(3):  # the membrane carries from frame to frame
+            x_t = jnp.asarray(frames[k][None], jnp.float32)
+            pool_p, spk_p, _, v_p = pallas(x_t, v_p)
+            pool_d, spk_d, _, v_d = dense(x_t, v_d)
+            assert v_p.shape == (3, hw[0], hw[1] * 16)
+            assert 0 < float(spk_d.mean()) < 1
+            np.testing.assert_array_equal(np.asarray(spk_p), np.asarray(spk_d))
+            np.testing.assert_array_equal(
+                np.asarray(ops.lane_dense_nhwc(v_p, 16)), np.asarray(v_d))
+            np.testing.assert_array_equal(np.asarray(pool_p), np.asarray(pool_d))
+
+    @pytest.mark.parametrize("converted", [False, True])
+    def test_detector_heads_match_dense(self, converted):
+        """The whole detector at 48×64 (8×8 encode blocks), streamed over 2
+        frames: heads and final membranes of the pallas executor equal the
+        dense oracle's. ``converted``: the ANN→SNN conversion settings —
+        soft reset, v_init, rate-coded encode, rate-gated pools."""
+        import dataclasses
+
+        from repro.configs import get_config, smoke_config
+        from repro.core import pruning
+        from repro.models import snn_yolo as sy
+
+        cfg = dataclasses.replace(
+            smoke_config(get_config("snn-det")), input_hw=(48, 64),
+            use_block_conv=True)
+        if converted:
+            cfg = dataclasses.replace(cfg, reset="soft", v_init=0.25,
+                                      rate_encode=True, pool_mode="rate")
+        params, bn = sy.init_params(jax.random.PRNGKey(1), cfg)
+        params = pruning.prune_tree(params, 0.8)
+        rng = np.random.default_rng(1)
+        frames = jnp.asarray(rng.integers(0, 256, (2, 2, 48, 64, 3)) / 255.0,
+                             jnp.float32)
+        bn = sy.calibrate_bn_state(params, bn, frames[0], cfg, iters=2)
+        out = {}
+        for ex in ("dense", "pallas"):
+            det = sy.compile_detector(dataclasses.replace(cfg, conv_exec=ex),
+                                      params, bn)
+            sess = det.new_session(batch=2)
+            heads = [np.asarray(sess.step(f).head) for f in frames]
+            # NHWC element order: the pallas encode leaf is (N, H, W·C)
+            mem = {k: np.asarray(v).reshape(
+                       v.shape[:2] + (-1, params[k]["w"].shape[-1]))
+                   for k, v in sess.state.items()}
+            out[ex] = heads, mem
+        for k in range(2):
+            np.testing.assert_array_equal(out["pallas"][0][k], out["dense"][0][k])
+        for k, v in out["dense"][1].items():
+            np.testing.assert_array_equal(out["pallas"][1][k], v, err_msg=k)

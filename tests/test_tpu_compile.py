@@ -1,4 +1,4 @@
-"""The fused kernel compiles for a TPU v5e at the paper config's layer shapes.
+"""The fused kernels compile for a TPU v5e at the paper config's layer shapes.
 
 Nothing runs: the installed TPU compiler compiles for a chip that is
 described (``v5e:2x2``) and not attached, and refuses what the chip's
@@ -17,10 +17,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.snn_det import CONFIG
 from repro.kernels import autotune, ops
+from repro.kernels import encode_pipeline as ep
 from repro.kernels import fused_pipeline as fp
 
-# encode (u8 input), conv_block (3×3, T 1→3), a 256-channel 3×3 and a 1×1
+# encode (the lane-dense kernel, u8 input), and three layers of the blocked
+# kernel: conv_block (3×3, T 1→3), a 256-channel 3×3 and a 1×1
 LAYERS = ("encode", "conv_block", "stage3/main_a", "stage2/agg")
+# the serving step's megabatch: 8 camera streams
+SERVE_BATCH = 8
 
 
 @pytest.fixture(scope="module")
@@ -68,32 +72,58 @@ def _layer_args(shape: autotune.LayerShape, sharding):
     gh, gw = ops._macro_grid(shape.h // shape.bh, shape.w // shape.bw, mr, mc)
     nb = gh * gw * mr * mc
     taps = shape.kh * shape.kw
-    dtype = jnp.float32 if shape.in_bits == 8 else jnp.int8
     args = (
         jax.ShapeDtypeStruct(
             (shape.t_in, nb, shape.bh + shape.kh - 1, shape.bw + shape.kw - 1, cin),
-            dtype, sharding=sharding),
+            jnp.int8, sharding=sharding),
         jax.ShapeDtypeStruct((kb, fp.AFFINE_ROWS, kblk), jnp.float32, sharding=sharding),
         jax.ShapeDtypeStruct((nb * shape.bh * shape.bw, kb * kblk), jnp.float32,
                              sharding=sharding),
         jax.ShapeDtypeStruct((kb, taps, cin, kblk), jnp.int8, sharding=sharding),
     )
     statics = dict(kh=shape.kh, kw=shape.kw, bh=shape.bh, bw=shape.bw, kblk=kblk,
-                   nbt=nbt, bpg=mr * mc, t_out=shape.t_out, in_bits=shape.in_bits,
+                   nbt=nbt, bpg=mr * mc, t_out=shape.t_out,
                    tap_alive=tuple(range(taps)))
     return args, statics
 
 
+def _encode_args(sharding):
+    """Shapes of the encode layer's dispatch in the serving step: the
+    megabatch's frames, the band matrices, the affine rows and the carried
+    lane-dense membrane."""
+    h, w = CONFIG.input_hw
+    bw, c = CONFIG.block_hw[1], CONFIG.stem_channels
+    wt = ep.input_tile(w, bw)
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+
+    return (shape((SERVE_BATCH, h, w, 3), jnp.float32),
+            shape((3, 3 * wt, bw * c), jnp.bfloat16),
+            shape((fp.AFFINE_ROWS, bw * c), jnp.float32),
+            shape((SERVE_BATCH, h, w * c), jnp.float32))
+
+
 @pytest.mark.parametrize("layer", LAYERS)
 def test_fused_kernel_compiles_for_v5e(layer, one_chip, no_compile_cache):
-    shape = autotune.detector_layer_shapes(CONFIG)[layer]
-    args, statics = _layer_args(shape, one_chip)
+    if layer == "encode":
+        args = _encode_args(one_chip)
 
-    def dispatch(x, affine, v0, wdense):
-        return fp.fused_pipeline_pallas(
-            x, None, None, affine, v0, wdense=wdense, bn_scale=0.5,
-            threshold=0.5, leak=0.25, interpret=False, **statics,
-        )
+        def dispatch(frames, bands, affine, v0):
+            return ops._dispatch_encode(
+                frames, bands, affine, v0, bh=CONFIG.block_hw[0],
+                bw=CONFIG.block_hw[1], t_out=1, bn_scale=0.5, threshold=0.5,
+                leak=0.25, reset="hard", v_init=0.0, interpret=False,
+            )
+    else:
+        shape = autotune.detector_layer_shapes(CONFIG)[layer]
+        args, statics = _layer_args(shape, one_chip)
+
+        def dispatch(x, affine, v0, wdense):
+            return fp.fused_pipeline_pallas(
+                x, None, None, affine, v0, wdense=wdense, bn_scale=0.5,
+                threshold=0.5, leak=0.25, interpret=False, **statics,
+            )
 
     compiled = jax.jit(dispatch).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
