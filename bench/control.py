@@ -20,6 +20,8 @@ import sys
 import reference as ref
 import run
 
+det = run.load_kind("detector")
+
 
 def control_bits(cfg_doc: dict) -> int:
     """The control's weight precision: the next integer width below the
@@ -27,7 +29,7 @@ def control_bits(cfg_doc: dict) -> int:
     return cfg_doc["model"]["weight_bits"] // 2
 
 
-def control_weights(cell: run.Cell) -> dict:
+def control_weights(cell) -> dict:
     """The reference's weights at the control's precision."""
     return ref.prepare(cell.net, cell.params, cell.bn, control_bits(cell.cfg_doc))
 
@@ -38,20 +40,20 @@ def readings(cell_name: str, seeds: list, seconds: float, program, *,
     cell_spec = run.find_cell(spec, cell_name)
     cfg_doc = run.load_json(run.BENCH / "configs" / f"{cell_spec['config']}.json")
     traffic = run.load_json(run.BENCH / "traffic" / f"{cell_spec['traffic']}.json")
-    cell = run.Cell(cfg_doc, traffic, program)
+    cell = det.Cell(cfg_doc, traffic, program)
     worst = {k: 0.0 for k in cfg_doc["limits"]}
     least = {k: float("inf") for k in cfg_doc["limits"]}
     lower = control_weights(cell)
     for seed in seeds:
         cell.start(seed)
         cell.warm_up()
-        win, _ = run.run_window(cell, seconds)
-        clips = run.sample_clips(win.clips, seed, traffic["check_clips"])
+        win = det.run_window(cell, seconds)
+        clips = det.sample_clips(win.clips, seed, traffic["check_clips"])
         cell.stop()
-        expected = run.replay(cell, clips, cell.ref_weights)
-        got = run.check(cell, clips, expected)
+        expected = det.replay(cell, clips, cell.ref_weights)
+        got = det.check(cell, clips, expected)
         # the control: the reference at the lower precision in the program's place
-        ctl = ref.compare(run.replay(cell, clips, lower), expected)
+        ctl = ref.compare(det.replay(cell, clips, lower), expected)
         for k in worst:
             worst[k] = max(worst[k], got[k])
             least[k] = min(least[k], ctl[k])
@@ -70,7 +72,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
     try:
-        program = run.import_program()
+        program = run.import_program(det)
         run.enable_cache()
         import jax
 

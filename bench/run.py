@@ -1,29 +1,44 @@
-"""Chip benchmark of the spiking detector's serving path.
+"""Chip benchmark of the program's serving paths.
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; the
+A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
 harness reads ``bench/configs/<config>.json`` and ``bench/traffic/<traffic>
-.json`` and, for ``--trace 1``, one reader ``bench/metrics/<metric>.py`` per
-per-layer metric of the cell, all found by name.
+.json``, the driver of the configuration's kind, ``bench/kinds/<kind>.py``
+(``"kind"`` in the configuration, ``"detector"`` where it names none), and,
+for ``--trace 1``, one reader ``bench/metrics/<metric>.py`` per per-layer
+metric of the cell, all found by name.
 
-Set-up makes the weights from the configuration's weight seed, builds the
-program's detector (``compile_detector`` with the pallas executor) and its
-``Engine`` at the cell's slot count, renders a pool of video frames from
-``--seed`` and warms the serving step up. The window then serves closed-loop
-camera streams for ``--seconds``: each stream submits its next clip when the
-last one finishes. A frame's latency runs from when the engine could first
-serve it (its clip's submission, or the delivery of the frame before it) to
-when its detections are on the host. After the window a sample of the
-finished clips, drawn from the seed, is replayed through the plain reference
-(``reference.py``) and compared.
+This file holds what every kind shares: the command line, the device and
+chip-count checks, the peaks (``peaks.json``), the persistent compilation
+cache, the compile counter, the set-up clock, the profiler window and the
+result line. A kind driver provides (``kinds/detector.py``, ``kinds/lm.py``):
+
+* ``STEP_MODULE``: the name of its serving step's module in the device
+  trace, from which ``trace_reduce`` takes the steps;
+* ``program()``: imports the parts of the program it drives;
+* ``validate(cfg_doc, traffic)``: raises ValueError on a configuration or
+  traffic mix it cannot run;
+* ``Cell(cfg_doc, traffic, program)`` with ``start(seed)``, ``warm_up()``
+  and ``stop()``: set-up, the run's inputs from the seed, the warm-up of
+  every shape the window uses, and the release of the program's state;
+* ``run_window(cell, seconds, profile)``: the timed window, which calls the
+  :class:`ProfileWindow` around each tick; it returns a window with
+  ``ticks``, ``attempted`` and ``failed``;
+* ``end_to_end(win)`` and ``summary(win)``: the end-to-end metrics and a
+  line for standard error;
+* ``verify(cell, win)``: frees the program's state, then compares a sample
+  of what the window served with the plain reference; returns ``(ok,
+  checks)``, each compared number beside its limit;
+* ``trace_context(cell, win, red, ticks, peaks)``: what its per-layer
+  readers read.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
 ``breakdown``, and ``checks`` last: each compared number with its limit).
 The harness exits nonzero, and prints no such line, when JAX finds no TPU or
-fewer chips than the cell asks for, or when the program's sources are not in
-the checkout.
+fewer chips than the cell asks for, when the program's sources are not in
+the checkout, or when a name it looks up has no file.
 """
 from __future__ import annotations
 
@@ -32,7 +47,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -40,16 +54,10 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 
-import numpy as np  # noqa: E402
-
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
-
-import frames as frames_mod  # noqa: E402
-import reference as ref  # noqa: E402
-import work as work_mod  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 # the traced part of a --trace 1 window: it starts this far into the
@@ -85,15 +93,35 @@ def cell_metrics(entries: list, cell: str) -> list:
     return [m for m in entries if "workloads" not in m or cell in m["workloads"]]
 
 
+_MODULES: dict = {}
+
+
+def load_module(folder: str, name: str, what: str):
+    """The module ``bench/<folder>/<name>.py``, loaded from its file once."""
+    path = BENCH / folder / f"{name}.py"
+    if path not in _MODULES:
+        if not path.is_file():
+            raise BenchError(f"no {what} {path} for {name!r}")
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{folder}_{name.replace('.', '_')}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
+
+
 def load_reader(name: str):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise BenchError(f"no reader {path} for metric {name!r}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name, "reader").read
+
+
+def kind_of(cfg_doc: dict) -> str:
+    return cfg_doc.get("kind", "detector")
+
+
+def load_kind(name: str):
+    """The driver ``bench/kinds/<name>.py`` of a kind of system."""
+    return load_module("kinds", name, "kind driver")
 
 
 def load_peaks(kind: str) -> dict:
@@ -103,18 +131,16 @@ def load_peaks(kind: str) -> dict:
     return peaks[kind]
 
 
-def import_program():
+def import_program(kind):
     """Put the checkout's ``src/`` first on the path and import the parts
-    of the program the benchmark drives."""
+    of the program that the kind driver ``kind`` drives."""
     src = ROOT / "src"
     if str(src) not in sys.path:
         sys.path.insert(0, str(src))
     try:
-        from repro.models import snn_yolo
-        from repro.serve import detector, engine
+        return kind.program()
     except ImportError as e:
         raise BenchError(f"the program's sources are not in {src}: {e}") from None
-    return snn_yolo, detector, engine
 
 
 def enable_cache() -> None:
@@ -148,210 +174,62 @@ class CompileWatch:
             self.misses += 1
 
 
-# -------------------------------------------------------------------- cell --
+# ------------------------------------------------------------- the window --
 
 
-class Cell:
-    """Everything set-up builds for one run of a cell."""
+class ProfileWindow:
+    """The traced part of a ``--trace 1`` window, driven by a kind's window
+    loop: the device-only profiler starts at the first tick that begins
+    ``TRACE_START_S`` or more into the window and stops after the first tick
+    that ends ``TRACE_LEN_S`` or more after it started (both shortened for
+    short windows). ``first`` and ``end`` are then the traced tick range."""
 
-    def __init__(self, cfg_doc: dict, traffic: dict, program):
-        snn_yolo, detector, engine = program
-        self.cfg_doc, self.traffic = cfg_doc, traffic
-        model = cfg_doc["model"]
-        self.net = ref.Net.from_model(model)
-        wts = cfg_doc["weights"]
-        post = cfg_doc["postprocess"]
-        self.post = (tuple(tuple(a) for a in post["anchors"]),
-                     post["score_threshold"], post["iou_threshold"],
-                     post["max_detections"])
-        bits = model["weight_bits"]
-        params = ref.make_params(self.net, wts["seed"], wts["prune_rate"])
-        calib = frames_mod.render_pool(wts["seed"], wts["calibration_frames"],
-                                       self.net.input_hw)
-        bn = ref.calibrate(self.net, params, calib, bits)
-        self.params, self.bn = params, bn
-        self.ref_weights = ref.prepare(self.net, params, bn, bits)
-        self.nnz = work_mod.nonzeros(self.ref_weights)
+    def __init__(self, trace_dir: str, seconds: float):
+        self.dir = trace_dir
+        self.at = min(TRACE_START_S, seconds / 4)
+        self.length = min(TRACE_LEN_S, seconds / 2)
+        self.first = self.end = self.started = None
 
-        self.det = snn_yolo.compile_detector(
-            snn_yolo.config_from_dict(model), params, bn,
-            anchors=self.post[0], score_threshold=self.post[1],
-            iou_threshold=self.post[2], max_detections=self.post[3])
-        self._detector, self._engine = detector, engine
-        self.core = self.engine = None
+    def before_tick(self, t0: float, n_ticks: int) -> None:
+        import jax
 
-    def start(self, seed: int) -> None:
-        """A fresh engine at the cell's slot count, and the frame pool and
-        clip sources of ``seed``."""
-        traffic = self.traffic
-        self.seed = seed
-        slots = traffic["slots"]
-        self.core = self._detector.DetectorEngineCore(self.det, n_slots=slots,
-                                                      min_bucket=slots)
-        self.engine = self._engine.Engine(core=self.core)
-        self.pool = frames_mod.render_pool(seed, traffic["pool_frames"],
-                                           self.net.input_hw,
-                                           max_drift_px=traffic["max_drift_px"])
-        self.sources = [
-            frames_mod.ClipSource(self.pool, seed, i,
-                                  clip_frames=traffic["clip_frames"],
-                                  stagger=traffic["first_clip_stagger"])
-            for i in range(traffic["streams"])
-        ]
-        self._rid = 0
-
-    def stop(self) -> None:
-        """Drop the engine and its device state."""
-        self.core = self.engine = None
-        gc.collect()
-
-    def submit(self, frames_view) -> object:
-        req = self._detector.FrameRequest(rid=self._rid, frames=frames_view)
-        self._rid += 1
-        res = self.engine.submit(req)
-        if not res:
-            raise RuntimeError(f"clip refused by the engine: {res.reason}")
-        return req
-
-    def warm_up(self) -> None:
-        """Serve one 2-frame clip per stream to the end: compiles (or loads)
-        the serving step at the cell's capacity bucket and runs the cold
-        admission and the staged upload once."""
-        for _ in self.sources:
-            self.submit(self.pool[:2])
-        result = self.engine.run()
-        if not result.drained:
-            raise RuntimeError("warm-up did not drain")
-        self.engine.finished.clear()
-
-
-class Window:
-    """The measured window: closed-loop streams, per-frame latencies, and
-    per-tick records."""
-
-    def __init__(self):
-        self.latencies: list[float] = []
-        self.ticks: list[tuple] = []  # (t_start, t_end, step_wall, frames)
-        self.clips: list = []  # every request submitted in the window
-        self.elapsed = 0.0
-
-
-def run_window(cell: Cell, seconds: float, *, trace_dir: str | None = None):
-    """Serve for ``seconds``; with ``trace_dir``, trace part of the window
-    with the JAX profiler (device only). Returns the Window and, when
-    traced, [first traced tick, end tick, host start]."""
-    import jax
-
-    win = Window()
-    streams = []  # per stream: [request, time its next frame became ready, frames seen]
-    traced = None
-    t0 = time.perf_counter()
-    for src in cell.sources:
-        req = cell.submit(src.next_clip())
-        win.clips.append(req)
-        streams.append([req, time.perf_counter(), 0])
-    trace_at = min(TRACE_START_S, seconds / 4)
-    trace_len = min(TRACE_LEN_S, seconds / 2)
-    steps = cell.core.step_wall
-    while True:
-        if trace_dir is not None and traced is None and time.perf_counter() - t0 >= trace_at:
+        if self.first is None and time.perf_counter() - t0 >= self.at:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 0
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            traced = [len(win.ticks), None, time.perf_counter()]
-        n_steps = len(steps)
-        ta = time.perf_counter()
-        cell.engine.run(max_steps=1)
-        tb = time.perf_counter()
-        wall = steps[-1] if len(steps) > n_steps else 0.0
-        delivered = 0
-        for st in streams:
-            req = st[0]
-            while st[2] < len(req.out):
-                win.latencies.append(tb - st[1])
-                st[1] = tb
-                st[2] += 1
-                delivered += 1
-        win.ticks.append((ta, tb, wall, delivered))
-        if tb - t0 >= seconds:
-            break
-        for i, st in enumerate(streams):
-            if st[0].done:
-                req = cell.submit(cell.sources[i].next_clip())
-                win.clips.append(req)
-                st[:] = [req, time.perf_counter(), 0]
-        if traced is not None and traced[1] is None and tb - traced[2] >= trace_len:
-            traced[1] = len(win.ticks)
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
+            self.first, self.started = n_ticks, time.perf_counter()
+
+    def after_tick(self, t_end: float, n_ticks: int) -> None:
+        if self.first is not None and self.end is None and t_end - self.started >= self.length:
+            self.close(n_ticks)
+
+    def close(self, n_ticks: int) -> None:
+        import jax
+
+        if self.first is not None and self.end is None:
+            self.end = n_ticks
             jax.profiler.stop_trace()
-    if traced is not None and traced[1] is None:
-        traced[1] = len(win.ticks)
-        jax.profiler.stop_trace()
-    win.elapsed = win.ticks[-1][1] - t0
-    return win, traced
-
-
-def end_to_end(win: Window) -> dict:
-    lat = np.asarray(win.latencies, np.float64)
-    frames = int(lat.size)
-    return {
-        "frames_per_s": frames / win.elapsed,
-        "frame_p50_ms": float(np.percentile(lat, 50) * 1e3),
-        "frame_p95_ms": float(np.percentile(lat, 95) * 1e3),
-    }
-
-
-def sample_clips(clips: list, seed: int, n: int) -> list:
-    """Up to ``n`` finished clips drawn from the seed: from those of the
-    longest length first, then from the rest."""
-    done = [c for c in clips if c.done]
-    rng = frames_mod.seed_rng(seed, 3)
-    longest = max((len(c.frames) for c in done), default=0)
-    full = [c for c in done if len(c.frames) == longest]
-    rest = [c for c in done if len(c.frames) < longest]
-    picked = [full[i] for i in rng.permutation(len(full))[:n]]
-    picked += [rest[i] for i in rng.permutation(len(rest))[:n - len(picked)]]
-    return picked
-
-
-def check(cell: Cell, clips: list, expected: list | None = None) -> dict:
-    """Compare what was served for ``clips`` with the reference's replay of
-    them (``expected``, replayed here when not given)."""
-    if expected is None:
-        expected = replay(cell, clips, cell.ref_weights)
-    return ref.compare([list(zip(c.heads, c.out)) for c in clips], expected)
-
-
-def replay(cell: Cell, clips: list, weights: dict) -> list:
-    return ref.replay(weights, [c.frames for c in clips], cell.net, cell.post,
-                      batch=cell.traffic["check_clips"])
-
-
-def checks_with_limits(found: dict, limits: dict, n_clips: int) -> tuple[bool, dict]:
-    """Each compared number beside its upper limit, and the number of clips
-    checked beside its lower one."""
-    out = {k: {"value": found[k], "limit": limits[k]} for k in limits}
-    ok = n_clips > 0 and all(found[k] is not None and found[k] <= limits[k]
-                             for k in limits)
-    out["clips_checked"] = {"value": n_clips, "limit": 1}
-    return ok, out
 
 
 def run_cell(cfg_doc: dict, traffic: dict, seed: int, seconds: float,
              trace: bool, peaks: dict, per_layer: list, end_metrics: list,
              program, *, log=sys.stderr) -> dict:
-    """One run of a cell; returns the result object."""
+    """One run of a cell; returns the result object. ``program`` is what
+    :func:`import_program` returned for the configuration's kind."""
     import jax
 
+    kind = load_kind(kind_of(cfg_doc))
     watch = CompileWatch()
-    cell = Cell(cfg_doc, traffic, program)
+    cell = kind.Cell(cfg_doc, traffic, program)
     cell.start(seed)
     cell.warm_up()
     setup_s = time.perf_counter() - T_START
     compiles_before = watch.compiles
     tmp = tempfile.TemporaryDirectory(prefix="bench-trace-") if trace else None
     try:
-        win, traced = run_window(cell, seconds, trace_dir=tmp.name if tmp else None)
+        profile = ProfileWindow(tmp.name, seconds) if tmp else None
+        win = kind.run_window(cell, seconds, profile)
         compiles_in_window = watch.compiles - compiles_before
         dev = jax.devices()[0]
         stats = dev.memory_stats() or {}
@@ -359,23 +237,14 @@ def run_cell(cfg_doc: dict, traffic: dict, seed: int, seconds: float,
         # programs' temporaries, which it does not count as in use
         peak = int(stats.get("peak_bytes_in_use", 0)) + int(
             stats.get("peak_bytes_reserved", stats.get("bytes_reserved", 0)))
-        e2e = end_to_end(win)
-        ticks_ms = [1e3 * (t[1] - t[0]) for t in win.ticks]
-        walls_ms = [1e3 * t[2] for t in win.ticks if t[2]]
-        print(f"window: {len(win.ticks)} ticks, {len(win.latencies)} frames, "
-              f"{win.elapsed:.3f} s; tick median {np.median(ticks_ms):.3f} ms, "
-              f"step_wall median {np.median(walls_ms):.3f} ms; "
-              f"compiles in window {compiles_in_window}; "
+        e2e = kind.end_to_end(win)
+        print(f"{kind.summary(win)}; compiles in window {compiles_in_window}; "
               f"peak bytes {peak}; cache hits {watch.hits} "
               f"misses {watch.misses}", file=log)
         print(f"setup_s {setup_s:.3f}; memory_stats {stats}", file=log)
-        rejected = len(cell.engine.rejected)
-        sampled = sample_clips(win.clips, seed, traffic["check_clips"])
-        cell.stop()  # free the program's device state before the reference runs
-        limits = cfg_doc["limits"]
-        # no finished clip to check: every number reads as failing
-        found = check(cell, sampled) if sampled else {k: None for k in limits}
-        ok, checks = checks_with_limits(found, limits, len(sampled))
+        t_check = time.perf_counter()
+        ok, checks = kind.verify(cell, win)  # frees the program's state first
+        print(f"check took {time.perf_counter() - t_check:.3f} s", file=log)
         metrics = {}
         breakdown = None
         device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -383,12 +252,9 @@ def run_cell(cfg_doc: dict, traffic: dict, seed: int, seconds: float,
         if trace:
             import trace_reduce
 
-            red = trace_reduce.reduce_dir(tmp.name)
-            ticks = win.ticks[traced[0]:traced[1]]
-            work = work_mod.count(cell.net, cell.nnz, peaks)
-            frames_traced = sum(t[3] for t in ticks)
-            ctx = {"trace": red, "ticks": ticks, "work": work, "peaks": peaks,
-                   "frames_per_step": frames_traced / max(1, sum(1 for t in ticks if t[2]))}
+            red = trace_reduce.reduce_dir(tmp.name, kind.STEP_MODULE)
+            ticks = win.ticks[profile.first:profile.end] if profile.first is not None else []
+            ctx = kind.trace_context(cell, win, red, ticks, peaks)
             for m in per_layer:
                 value = load_reader(m["name"])(ctx)
                 if value is not None:
@@ -405,8 +271,8 @@ def run_cell(cfg_doc: dict, traffic: dict, seed: int, seconds: float,
             tmp.cleanup()
     for k, v in checks.items():
         print(f"check {k}: {v['value']} (limit {v['limit']})", file=log)
-    result = {"correct": ok and rejected == 0, "attempted": len(win.latencies),
-              "failed": rejected, "metrics": metrics, "device": device}
+    result = {"correct": ok and win.failed == 0, "attempted": win.attempted,
+              "failed": win.failed, "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
     result["checks"] = checks
@@ -425,7 +291,12 @@ def main(argv=None) -> int:
         cell = find_cell(spec, args.workload)
         cfg_doc = load_json(BENCH / "configs" / f"{cell['config']}.json")
         traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
-        program = import_program()
+        kind = load_kind(kind_of(cfg_doc))
+        try:
+            kind.validate(cfg_doc, traffic)
+        except (KeyError, ValueError) as e:
+            raise BenchError(f"cell {args.workload!r}: {e}") from None
+        program = import_program(kind)
         enable_cache()
         import jax
 

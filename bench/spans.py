@@ -40,7 +40,8 @@ import reference as ref  # noqa: E402
 import run  # noqa: E402
 import trace_reduce  # noqa: E402
 import trace_spans  # noqa: E402
-import work as work_mod  # noqa: E402
+
+det = run.load_kind("detector")
 
 NEW_METRICS = ("dispatch_ms", "upload_ms", "copyout_ms", "encode_ms")
 WINDOWS = ("off", "on", "off", "on")
@@ -81,7 +82,7 @@ def measure(cfg_doc: dict, traffic: dict, seed: int, seconds: float, peaks: dict
             per_layer: list, program, *, keep: str | None = None, log=sys.stderr) -> dict:
     from repro.serve.trace import Tracer
 
-    cell = run.Cell(cfg_doc, traffic, program)
+    cell = det.Cell(cfg_doc, traffic, program)
     medians: dict = {"off": [], "on": []}
     summary = None
     for mode in WINDOWS:
@@ -89,7 +90,7 @@ def measure(cfg_doc: dict, traffic: dict, seed: int, seconds: float, peaks: dict
         cell.warm_up()
         tracer = Tracer(enabled=mode == "on")
         cell.core.tracer = tracer
-        win, _ = run.run_window(cell, seconds)
+        win = det.run_window(cell, seconds)
         medians[mode].append(float(np.median([1e3 * (t[1] - t[0]) for t in win.ticks])))
         if mode == "on":
             summary = tracer.summary()
@@ -102,7 +103,9 @@ def measure(cfg_doc: dict, traffic: dict, seed: int, seconds: float, peaks: dict
     cell.core.tracer = tracer
     tmp = tempfile.TemporaryDirectory(prefix="bench-spans-")
     try:
-        win, traced = run.run_window(cell, seconds, trace_dir=tmp.name)
+        profile = run.ProfileWindow(tmp.name, seconds)
+        win = det.run_window(cell, seconds, profile)
+        traced = (profile.first, profile.end)
         traced_ms = float(np.median([1e3 * (t[1] - t[0]) for t in win.ticks]))
         counters = dict(tracer.counters)
         gauges = dict(tracer.gauges)
@@ -110,7 +113,7 @@ def measure(cfg_doc: dict, traffic: dict, seed: int, seconds: float, peaks: dict
         hlo = serving_hlo(cell)
         names = [lay.name for lay in ref.layers(cell.net)]
         scopes = trace_spans.layer_scopes(names)
-        red = trace_reduce.reduce_dir(tmp.name)
+        red = trace_reduce.reduce_dir(tmp.name, det.STEP_MODULE)
         extra = trace_spans.reduce_dir(tmp.name, spans, scopes,
                                        trace_spans.hlo_op_paths(hlo))
         if keep:
@@ -123,9 +126,7 @@ def measure(cfg_doc: dict, traffic: dict, seed: int, seconds: float, peaks: dict
         tmp.cleanup()
 
     ticks = win.ticks[traced[0]:traced[1]]
-    ctx = {"trace": {**red, **extra}, "ticks": ticks,
-           "work": work_mod.count(cell.net, cell.nnz, peaks), "peaks": peaks,
-           "frames_per_step": sum(t[3] for t in ticks) / max(1, sum(1 for t in ticks if t[2])),
+    ctx = {**det.trace_context(cell, win, {**red, **extra}, ticks, peaks),
            "spans": spans, "counters": counters}
     metrics = {}
     for name in [m["name"] for m in per_layer] + list(NEW_METRICS):
@@ -166,7 +167,7 @@ def main(argv=None) -> int:
         cell = run.find_cell(spec, args.workload)
         cfg_doc = run.load_json(BENCH / "configs" / f"{cell['config']}.json")
         traffic = run.load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
-        program = run.import_program()
+        program = run.import_program(det)
         run.enable_cache()
         import jax
 

@@ -37,6 +37,7 @@ import trace_reduce as tr  # noqa: E402
 import work  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+DET = run.load_kind("detector")
 PEAKS = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
 SMOKE_MODEL = dict(input_hw=[24, 32], stem_channels=8, conv_block_channels=8,
                    stage_channels=[[8, 8], [8, 8], [8, 16], [16, 16], [16, 16]],
@@ -60,7 +61,7 @@ def run_smoke(workload: str = "mixed-fleet8", *, trace: bool = False,
     return run.run_cell(doc, traffic, seed, seconds, trace, PEAKS,
                         run.cell_metrics(SPEC["per_layer"], workload),
                         run.cell_metrics(SPEC["end_to_end"], workload),
-                        run.import_program())
+                        run.import_program(DET))
 
 
 # ---------------------------------------------------------------- by name --
@@ -75,16 +76,21 @@ def test_cells_configs_traffic_and_readers_found_by_name():
     for path in sorted((BENCH / "configs").glob("*.json")):
         doc = run.load_json(path)
         assert doc["name"] == path.stem
+        if run.kind_of(doc) != "detector":
+            continue  # the other kinds' configurations: test_lm.py
         snn_yolo.config_from_dict(doc["model"])  # every model key is the program's
         assert set(doc["limits"]) == {"head_gap", "det_mismatch"}
-    for path in sorted((BENCH / "traffic").glob("*.json")):
-        traffic = run.load_json(path)
-        assert traffic["slots"] >= traffic["streams"] >= 1
-        assert traffic["clip_frames"] - traffic["first_clip_stagger"] * (traffic["streams"] - 1) >= 1
     for cell in SPEC["workloads"]:
         assert cell["config"] in names
-        assert (BENCH / "traffic" / f"{cell['traffic']}.json").is_file()
-    computed = {"frames_per_s", "frame_p50_ms", "frame_p95_ms", "setup_s"}
+        doc = run.load_json(BENCH / "configs" / f"{cell['config']}.json")
+        traffic = run.load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
+        run.load_kind(run.kind_of(doc)).validate(doc, traffic)
+    for path in sorted((BENCH / "traffic").glob("*.json")):
+        traffic = run.load_json(path)
+        if "streams" in traffic:  # a detector mix
+            DET.validate(run.load_json(BENCH / "configs" / "snn-det-mixed.json"), traffic)
+    computed = {"frames_per_s", "frame_p50_ms", "frame_p95_ms", "setup_s",
+                "tokens_per_s", "ttft_p50_ms", "itl_p50_ms"}
     assert {m["name"] for m in SPEC["end_to_end"]} <= computed
     for m in SPEC["per_layer"]:
         read = run.load_reader(m["name"])
@@ -135,7 +141,8 @@ def test_trace_reduction_on_hand_built_events():
     ctx = {"trace": red, "ticks": [(0.0, 0.010, 0.008, 4), (0.010, 0.030, 0.012, 4)],
            "work": {"step_ops": 1e3, "fused_roofline_s": 1e-8}, "peaks": PEAKS,
            "frames_per_step": 4.0}
-    got = {m["name"]: run.load_reader(m["name"])(ctx) for m in SPEC["per_layer"]}
+    got = {m["name"]: run.load_reader(m["name"])(ctx)
+           for m in run.cell_metrics(SPEC["per_layer"], "mixed-fleet8")}
     assert got["idle_share"] == pytest.approx(59.0)
     assert got["step_device_ms"] == pytest.approx(200e-6)
     assert got["kernel_ms"] == pytest.approx(100e-6)
@@ -246,7 +253,8 @@ def test_result_line_schema(trace):
         # the CPU trace has no TPU plane: per-layer readers of it read nothing
         assert set(res["metrics"]) <= {"tick_host_ms"}
     else:
-        assert set(res["metrics"]) == {m["name"] for m in SPEC["end_to_end"]}
+        want = run.cell_metrics(SPEC["end_to_end"], "mixed-fleet8")
+        assert set(res["metrics"]) == {m["name"] for m in want}
     for m in res["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     for c in res["checks"].values():
@@ -287,15 +295,15 @@ def test_control_fails_the_comparison():
     """The reference at FXP4 in the program's place (the control) fails a
     limit that the sound run meets."""
     doc, traffic = smoke_cell("snn-det-mixed")
-    cell = run.Cell(doc, traffic, run.import_program())
+    cell = DET.Cell(doc, traffic, run.import_program(DET))
     cell.start(2**31 + 3)
     cell.warm_up()
-    win, _ = run.run_window(cell, 1.0)
-    clips = run.sample_clips(win.clips, cell.seed, traffic["check_clips"])
+    win = DET.run_window(cell, 1.0)
+    clips = DET.sample_clips(win.clips, cell.seed, traffic["check_clips"])
     cell.stop()
-    expected = run.replay(cell, clips, cell.ref_weights)
-    sound = run.check(cell, clips, expected)
-    ctl = ref.compare(run.replay(cell, clips, control.control_weights(cell)), expected)
+    expected = DET.replay(cell, clips, cell.ref_weights)
+    sound = DET.check(cell, clips, expected)
+    ctl = ref.compare(DET.replay(cell, clips, control.control_weights(cell)), expected)
     limits = doc["limits"]
     assert sound["frames"] == ctl["frames"] > 0
     assert all(sound[k] <= limits[k] for k in limits)
@@ -306,6 +314,8 @@ def test_control_fails_the_comparison():
 def test_control_is_one_precision_below_the_configuration():
     for cfg in SPEC["configs"]:
         doc = run.load_json(ROOT / cfg["file"])
+        if run.kind_of(doc) != "detector":
+            continue  # the LM kind's e4m3 control: test_lm.py
         assert doc["model"]["weight_bits"] == 8
         assert control.control_bits(doc) == 4
 
@@ -366,6 +376,6 @@ def test_faults_make_correct_false(kind, monkeypatch):
     traffic["check_clips"] = 32
     res = run.run_cell(doc, traffic, 2**31 + 11, 1.5, False, PEAKS, [],
                        run.cell_metrics(SPEC["end_to_end"], "mixed-fleet8"),
-                       run.import_program())
+                       run.import_program(DET))
     assert res["correct"] is False
     assert res["checks"]["head_gap"]["value"] > res["checks"]["head_gap"]["limit"]

@@ -179,7 +179,7 @@ def test_measure_at_the_smoke_size():
     doc, traffic = smoke_cell("snn-det-mixed")
     out = spans_tool.measure(doc, traffic, 2**31 + 17, 0.5, PEAKS,
                              run.cell_metrics(SPEC["per_layer"], "mixed-fleet8"),
-                             run.import_program())
+                             run.import_program(run.load_kind("detector")))
     assert len(out["tick_ms"]["off"]) == len(out["tick_ms"]["on"]) == 2
     # the CPU trace has no TPU plane: the device readings are empty, the
     # span readers read the program's spans

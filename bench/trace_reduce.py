@@ -2,7 +2,8 @@
 
 What the TPU trace holds (read by hand from a v5e trace): the device plane
 ``/device:TPU:<n>`` has a line ``XLA Modules`` with one event per execution
-of a compiled program (the serving step is ``jit__masked(<hash>)``) and a
+of a compiled program (the detector's serving step is ``jit__masked(<hash>)``,
+the LM's decode step ``jit_decode(<hash>)``; a kind driver names its own) and a
 line ``XLA Ops`` with one event per HLO operation, named by its HLO text
 (``%name = type op(...), ...``). A Pallas kernel is a ``custom-call`` whose
 text names the target ``tpu_custom_call`` (the fused layers appear as
@@ -79,16 +80,17 @@ def _gaps(intervals, lo, hi):
     return out
 
 
-def reduce(ev: Events) -> dict:
+def reduce(ev: Events, step_module: str = STEP_MODULE) -> dict:
     """Per-step and window numbers of one device.
 
-    Steps are the executions of the serving program; the window runs from
+    Steps are the executions of the serving program, the modules whose name
+    starts with ``step_module``; the window runs from
     the first step's start to the last step's start, and the steps counted
     are those that start inside it. A step's ops are those that start
     inside its execution. Busy time is the union of all op intervals
     clipped to the window. Idle gaps are named by whether they fall inside
     a step's execution or between steps."""
-    steps = sorted((s, e) for n, s, e in ev.modules if n.startswith(STEP_MODULE))
+    steps = sorted((s, e) for n, s, e in ev.modules if n.startswith(step_module))
     if len(steps) < 2:
         return empty()
     lo, hi = steps[0][0], steps[-1][0]
@@ -155,11 +157,11 @@ def load(path: str) -> list:
     return devices
 
 
-def reduce_dir(trace_dir: str) -> dict:
+def reduce_dir(trace_dir: str, step_module: str = STEP_MODULE) -> dict:
     """Reduce the trace written under ``trace_dir``, averaged over the
-    device planes that ran the serving step."""
+    device planes that ran the serving step ``step_module``."""
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
-    reds = [reduce(ev) for ev in (load(paths[0]) if paths else [])]
+    reds = [reduce(ev, step_module) for ev in (load(paths[0]) if paths else [])]
     reds = [r for r in reds if r["n_steps"]]
     if not reds:
         return empty()
